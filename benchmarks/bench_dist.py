@@ -92,12 +92,12 @@ def run(bandwidth: float, smoke: bool = False) -> None:
 
         if smoke:
             # end-to-end sharded CG must actually converge in CI
-            n = 225
             from repro.launch.dist_solve import build_system
 
-            a, xstar, b = build_system(n)
+            host, xstar, b = build_system(6)
+            n = host[3][0]
             Ad = DistCsr.from_matrix(
-                sparse.csr_from_dense(a), Partition.uniform(n, min(ndev, 8))
+                sparse.csr_from_arrays(*host), Partition.uniform(n, min(ndev, 8))
             )
             res = krylov.cg(
                 Ad, jnp.asarray(b), stop=Stop(max_iters=500), executor=ex

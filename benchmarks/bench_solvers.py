@@ -170,8 +170,6 @@ def run_ir(small: bool = False, smoke: bool = False) -> None:
     inner-operator storage.  ``smoke=True`` runs one small system and asserts
     convergence (the CI gate for the IR path).
     """
-    from jax import experimental as jax_experimental
-
     from repro.precond import unit_roundoff
 
     suite = spd_suite(small or smoke)
@@ -179,7 +177,7 @@ def run_ir(small: bool = False, smoke: bool = False) -> None:
         name = "stencil2d_32"
         suite = {name: suite[name]}
     stop = solvers.Stop(max_iters=200, reduction_factor=1e-12)
-    with jax_experimental.enable_x64(True), use_executor(XlaExecutor()):
+    with jax.enable_x64(True), use_executor(XlaExecutor()):
         for mat_name, a in suite.items():
             a = a.astype(np.float64)
             n = a.shape[0]

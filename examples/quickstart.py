@@ -93,9 +93,7 @@ def linop_demo():
               f"err={float(jnp.abs(res.x - xstar).max()):.2e}")
 
         # mixed-precision IR: f32 inner CG under an f64 outer residual
-        from jax import experimental as jax_experimental
-
-        with jax_experimental.enable_x64(True):
+        with jax.enable_x64(True):
             A64 = sparse.csr_from_dense(a.astype(np.float64))
             b64 = jnp.asarray(a.astype(np.float64) @ np.linspace(-1, 1, n))
             res = solvers.mixed_precision_ir(

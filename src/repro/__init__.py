@@ -13,3 +13,8 @@ Subpackages:
 """
 
 __version__ = "0.1.0"
+
+# Importing the library registers the Pallas kernel space (Ginkgo: the device
+# backends are linked into the library), so a Pallas executor finds every
+# kernel whatever the caller happened to import first.
+from repro import kernels as _kernels  # noqa: E402,F401

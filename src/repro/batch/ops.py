@@ -7,7 +7,7 @@ Same three-space contract as the single-system ops (:mod:`repro.sparse.ops`):
 * xla       — one vectorized formulation over the whole batch (``vmap`` /
   broadcast einsum) the compiler fuses into a single launch;
 * pallas    — registered from :mod:`repro.kernels.spmv_batch_ell` (batch on
-  the outer grid axis; imported lazily by ``repro.kernels``).
+  the outer grid axis; bound when ``repro`` is imported).
 
 All batched vectors are ``(nb, n)``; batched scalars are ``(nb,)``.
 """

@@ -34,6 +34,7 @@ from repro.core.executor import (
     XlaExecutor,
     current_executor,
     default_executor,
+    executor_for_device,
     make_executor,
     reset_default_executor,
     use_executor,
@@ -46,6 +47,7 @@ from repro.core.params import (
     TPU_V5E,
     HardwareParams,
     get_target,
+    params_for_device,
 )
 from repro.core.registry import (
     NotCompiledError,
@@ -75,6 +77,7 @@ __all__ = [
     "PallasInterpretExecutor",
     "current_executor",
     "default_executor",
+    "executor_for_device",
     "reset_default_executor",
     "use_executor",
     "make_executor",
@@ -83,6 +86,7 @@ __all__ = [
     "tuning",
     "HardwareParams",
     "get_target",
+    "params_for_device",
     "TPU_V5E",
     "TPU_V4",
     "CPU_INTERPRET",

@@ -57,7 +57,7 @@ def lane_mask_type(warp_size: int):
         if not jax.config.jax_enable_x64:
             raise ValueError(
                 "64-lane warp ballots need uint64 lane masks; enable x64 "
-                "(e.g. `with jax.experimental.enable_x64():`) — the paper's "
+                "(e.g. `with jax.enable_x64(True):`) — the paper's "
                 "AMD wavefront-64 case maps to this configuration"
             )
         return jnp.uint64

@@ -50,6 +50,7 @@ __all__ = [
     "current_executor",
     "use_executor",
     "default_executor",
+    "executor_for_device",
     "reset_default_executor",
     "make_executor",
 ]
@@ -207,20 +208,22 @@ _CURRENT: contextvars.ContextVar[Optional[Executor]] = contextvars.ContextVar(
 _DEFAULT: Optional[Executor] = None
 
 
-def default_executor() -> Executor:
-    """Pick the natural executor for the runtime platform (cached).
+def executor_for_device(device) -> Executor:
+    """The natural executor for a JAX device, from its ``device_kind``.
 
-    TPU -> PallasTpuExecutor; anything else -> XlaExecutor.  (Mirrors Ginkgo
-    applications constructing ``CudaExecutor`` when a GPU is present and
-    ``OmpExecutor`` otherwise.)
+    A TPU v5e ("TPU v5 lite") -> PallasTpuExecutor(tpu_v5e); the CPU ->
+    XlaExecutor(cpu_xla).  (Mirrors Ginkgo applications constructing
+    ``CudaExecutor`` when a GPU is present and ``OmpExecutor`` otherwise.)
+    A kind without a hardware table raises.
     """
+    return _executor_for_params(params_lib.params_for_device(device))
+
+
+def default_executor() -> Executor:
+    """The executor for the first JAX device (cached)."""
     global _DEFAULT
     if _DEFAULT is None:
-        platform = jax.devices()[0].platform
-        if platform == "tpu":
-            _DEFAULT = PallasTpuExecutor(params_lib.TPU_V5E)
-        else:
-            _DEFAULT = XlaExecutor(params_lib.CPU_XLA)
+        _DEFAULT = executor_for_device(jax.devices()[0])
     return _DEFAULT
 
 

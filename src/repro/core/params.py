@@ -6,8 +6,11 @@ per backend with architecture-specific parameters (warp size 32 vs 64,
 (per-target machine model: tile geometry, subgroup size, memory budgets, roofline
 constants) which both the Pallas kernels and the roofline analysis read.
 
-All bandwidth/FLOP constants are the grading harness' TPU v5e numbers:
-197 TFLOP/s bf16 per chip, 819 GB/s HBM, ~50 GB/s/link ICI.
+The TPU v5e peaks are the published per-chip figures of Google Cloud's
+"TPU v5e" documentation page: 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s,
+1,600 Gbit/s (200 GB/s) of chip-to-chip interconnect.  :func:`params_for_device`
+maps a JAX device's ``device_kind`` to its entry; a kind that is not in
+:data:`DEVICE_KINDS` is an error, never a guess.
 """
 
 from __future__ import annotations
@@ -66,6 +69,10 @@ class HardwareParams:
 # correctness oracle; our reference space plays that role, and interpret mode
 # lets us validate the hardware-native kernels without the hardware).
 
+# Peaks: Google Cloud, "TPU v5e" (bf16 FLOP/s, HBM capacity and bandwidth,
+# 1,600 Gbit/s ICI).  The page publishes no f32 peak; peak_flops_f32 is a
+# quarter of bf16, an assumption.  VMEM: 96 of the core's 128 MiB, the most
+# one kernel may ask for through its compiler parameters.
 TPU_V5E = HardwareParams(
     name="tpu_v5e",
     kernel_space="pallas",
@@ -77,7 +84,7 @@ TPU_V5E = HardwareParams(
     peak_flops_bf16=197e12,
     peak_flops_f32=49e12,
     hbm_bandwidth=819e9,
-    ici_bandwidth=50e9,
+    ici_bandwidth=200e9,
 )
 
 TPU_V4 = HardwareParams(
@@ -129,6 +136,25 @@ TARGETS: Mapping[str, HardwareParams] = {
     p.name: p
     for p in (TPU_V5E, TPU_V4, CPU_INTERPRET, CPU_XLA, CPU_REFERENCE)
 }
+
+
+#: ``jax.Device.device_kind`` -> target.  Only kinds whose peaks are sourced
+#: above; a v4 (kind "TPU v4") still has to be checked against its own page.
+DEVICE_KINDS: Mapping[str, HardwareParams] = {
+    "TPU v5 lite": TPU_V5E,
+    "cpu": CPU_XLA,
+}
+
+
+def params_for_device(device) -> HardwareParams:
+    """The hardware table for a JAX device, keyed by its ``device_kind``."""
+    try:
+        return DEVICE_KINDS[device.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no hardware table for {device.platform} device kind "
+            f"{device.device_kind!r}; known kinds: {sorted(DEVICE_KINDS)}"
+        ) from None
 
 
 def get_target(name: str) -> HardwareParams:

@@ -188,15 +188,9 @@ def register_spec(spec: TuningSpec) -> TuningSpec:
     return spec
 
 
-def _ensure_specs_loaded() -> None:
-    # kernel families register their specs from their ops.py bindings; pulling
-    # in repro.kernels is the analogue of linking the device backends.
-    import repro.kernels  # noqa: F401
-
-
 def get_spec(op: str) -> TuningSpec:
-    if op not in _SPECS:
-        _ensure_specs_loaded()
+    # kernel families register their specs from their ops.py bindings, which
+    # importing the package runs (repro/__init__.py)
     try:
         return _SPECS[op]
     except KeyError:
@@ -206,7 +200,6 @@ def get_spec(op: str) -> TuningSpec:
 
 
 def all_specs() -> Dict[str, TuningSpec]:
-    _ensure_specs_loaded()
     return dict(_SPECS)
 
 

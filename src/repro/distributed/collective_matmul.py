@@ -28,8 +28,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import axis_size
-
 
 def ring_reduce_scatter_matmul(
     x: jax.Array,  # (m, k_local) — this shard's contraction slice
@@ -42,7 +40,7 @@ def ring_reduce_scatter_matmul(
     reduction decomposed into a ring so each ppermute overlaps the next
     partial matmul.
     """
-    size = axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     n = w.shape[1]
     if n % size:
@@ -74,7 +72,7 @@ def ring_all_gather_matmul(
     Each step matmuls the chunk currently held and forwards it — the full x is
     never resident; communication hides behind the running matmul.
     """
-    size = axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     m_local = x.shape[0]
     out = jnp.zeros((m_local * size, w.shape[1]), x.dtype)

@@ -145,10 +145,11 @@ def _ell_arrays(ip, j, v, n_rows_pad: int, k: int):
     """One part's CSR triplet -> padded row-major ELL arrays."""
     cols = np.zeros((n_rows_pad, k), np.int32)
     vals = np.zeros((n_rows_pad, k), v.dtype)
-    for r in range(len(ip) - 1):
-        a, b = ip[r], ip[r + 1]
-        cols[r, : b - a] = j[a:b]
-        vals[r, : b - a] = v[a:b]
+    # entry t of the CSR stream lands at (row[t], t - ip[row[t]])
+    rows = np.repeat(np.arange(len(ip) - 1, dtype=np.int64), np.diff(ip))
+    pos = np.arange(len(j), dtype=np.int64) - np.asarray(ip)[:-1][rows]
+    cols[rows, pos] = j
+    vals[rows, pos] = v
     return cols, vals
 
 
@@ -204,7 +205,7 @@ class DistLinOp(LinOp):
 
     # -- the global apply (replicated global vector in / out) ------------------
     def _apply(self, x, executor):
-        from repro.launch.mesh import make_shard_mesh, shard_map
+        from repro.launch.mesh import make_shard_mesh
         from jax.sharding import PartitionSpec as P
 
         part = self.partition
@@ -218,11 +219,14 @@ class DistLinOp(LinOp):
             return op.apply(x_l[0])[None]
 
         vec_spec = P(self.axis_name, *([None] * (xp.ndim - 1)))
-        yp = shard_map(
+        yp = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(shard_specs(leaves), vec_spec),
             out_specs=vec_spec,
+            # the local SpMV may be a Pallas kernel, which carries no
+            # varying-axes types; the output is sharded, not replicated
+            check_vma=False,
         )(leaves, xp)
         return part.unpad(yp)
 

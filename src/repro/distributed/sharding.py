@@ -37,16 +37,6 @@ MODEL_AXES = ("expert", "mlp", "heads", "kv_heads", "kv_seq", "vocab")
 ZERO_AXES = ("embed", "expert_mlp", "mlp", "heads", "vocab")
 
 
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` where available; psum(1) inside older jax's
-    collective bodies (same value, both resolve at trace time)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-
 # -- padded-shard reduction hygiene --------------------------------------------
 #
 # Distributed vectors are padded to a uniform per-shard length (``Lmax``) so

@@ -79,14 +79,14 @@ def _shard_map_blas(partition: Partition, body, *operands):
     stacked ``(P,)`` (identical across shards after the psum).
     """
     from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import make_shard_mesh, shard_map
+    from repro.launch.mesh import make_shard_mesh
     from repro.distributed.matrix import DATA_AXIS
 
     mesh = make_shard_mesh(partition.num_parts, DATA_AXIS)
     mask = jnp.asarray(partition.pad_mask)
     args = (mask,) + operands
     specs = tuple(P(DATA_AXIS, *([None] * (a.ndim - 1))) for a in args)
-    return shard_map(body, mesh=mesh, in_specs=specs, out_specs=P(DATA_AXIS))(
+    return jax.shard_map(body, mesh=mesh, in_specs=specs, out_specs=P(DATA_AXIS))(
         *args
     )
 

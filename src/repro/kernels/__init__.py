@@ -1,9 +1,8 @@
 """repro.kernels — hardware-native Pallas TPU kernels (the CUDA/HIP slot).
 
 Importing this package registers every Pallas implementation in the operation
-registry (the analogue of compiling Ginkgo's device backends: without this
-import, executors fall back to the ``xla`` / ``reference`` kernel spaces, or
-raise ``NotCompiledError`` in strict mode).
+registry (the analogue of linking Ginkgo's device backends); ``import repro``
+imports it, so every executor sees the same kernel spaces.
 
 Layout: one directory per hot-spot, each with
   kernel.py — ``pl.pallas_call`` + explicit BlockSpec VMEM tiling

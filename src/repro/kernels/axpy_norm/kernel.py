@@ -6,12 +6,15 @@ the stopping criterion.  Unfused, that is three HBM round trips over the
 vector (write z, read z, reduce); fused, the updated tile is reduced while it
 is still in VMEM — one read of x and y, one write of z, and a scalar.
 
-Grid = (n / block_n,): each step writes its z tile and adds ``Σ z²`` into a
-(1, 1) accumulator block revisited by every step (TPU grids iterate
-sequentially, so the read-modify-write is well-defined — the
-:mod:`repro.kernels.spmv_ell` idiom).  ``alpha`` rides as a (1, 1) operand so
+The vectors stream as lane-dense ``(rows, 128)`` views
+(:mod:`repro.kernels.lanes`).  Each grid step writes its z block and adds its
+column sums of ``z²`` into a ``(1, 128)`` accumulator block revisited by
+every step (TPU grids iterate in order, so the read-modify-write is
+well-defined); the 128 partial sums are added outside.  ``alpha`` rides in
+SMEM as a ``(1, 1)`` operand of the accumulation dtype (at least f32), so
 the kernel stays trace-compatible with solver loops where it is a traced
-scalar.  Tail padding (x = y = 0) produces z = 0 and adds nothing.
+scalar.  Tail padding (x = y = 0) produces z = 0
+and adds nothing.
 """
 
 from __future__ import annotations
@@ -21,18 +24,26 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import lanes
+
+
+def vmem_bytes(block_n: int, itemsize: int) -> int:
+    """Scoped VMEM of one launch: double-buffered x, y and z blocks, the
+    accumulator and the compiler's scratch."""
+    return 2 * 3 * block_n * itemsize + lanes.LANES * 4 + lanes.MOSAIC_SCRATCH_BYTES
 
 
 def _axpy_norm_kernel(alpha_ref, x_ref, y_ref, z_ref, ss_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         ss_ref[...] = jnp.zeros_like(ss_ref)
 
-    z = alpha_ref[0, 0] * x_ref[...] + y_ref[...]
-    z_ref[...] = z
-    ss_ref[0, 0] += jnp.sum(z * z).astype(ss_ref.dtype)
+    acc = ss_ref.dtype
+    z = alpha_ref[0, 0] * x_ref[...].astype(acc) + y_ref[...].astype(acc)
+    z_ref[...] = z.astype(z_ref.dtype)
+    ss_ref[...] += jnp.sum(z * z, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -41,34 +52,30 @@ def axpy_norm(
     x: jax.Array,
     y: jax.Array,
     *,
-    block_n: int = 1024,
+    block_n: int = 8192,
     interpret: bool = False,
 ):
     """(z, z·z) with z = alpha*x + y, computed in one pass over the vectors."""
     n = x.shape[0]
-    block_n = max(min(block_n, n), 1)
-    pn = ((n + block_n - 1) // block_n) * block_n
-    if pn != n:
-        x = jnp.pad(x, (0, pn - n))
-        y = jnp.pad(y, (0, pn - n))
-    alpha2d = jnp.asarray(alpha, x.dtype).reshape(1, 1)
-
+    rows, block_rows = lanes.row_tiling(n, block_n, x.dtype)
+    vec = pl.BlockSpec((block_rows, lanes.LANES), lambda i: (i, 0))
+    acc = jnp.promote_types(x.dtype, jnp.float32)
     z, ss = pl.pallas_call(
         _axpy_norm_kernel,
-        grid=(pn // block_n,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
+        grid=(rows // block_rows,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), vec, vec],
+        out_specs=[vec, pl.BlockSpec((1, lanes.LANES), lambda i: (0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((pn,), x.dtype),
-            jax.ShapeDtypeStruct((1, 1), x.dtype),
+            jax.ShapeDtypeStruct((rows, lanes.LANES), x.dtype),
+            jax.ShapeDtypeStruct((1, lanes.LANES), acc),
         ],
+        compiler_params=lanes.compiler_params(
+            vmem_bytes(block_rows * lanes.LANES, x.dtype.itemsize)
+        ),
         interpret=interpret,
-    )(alpha2d, x, y)
-    return z[:n], ss[0, 0]
+    )(
+        jnp.asarray(alpha, acc).reshape(1, 1),
+        lanes.to_rows(x, rows),
+        lanes.to_rows(y, rows),
+    )
+    return lanes.from_rows(z, n), jnp.sum(ss).astype(x.dtype)

@@ -12,19 +12,13 @@ the family's single-vector kernel).
 from __future__ import annotations
 
 from repro.core import registry, tuning
-from repro.kernels.axpy_norm.kernel import axpy_norm as axpy_norm_pallas
-
-
-def _vmem_bytes(shapes, block) -> int:
-    # x, y, z tiles plus the scalar accumulator
-    bn = block["block_n"]
-    itemsize = shapes.get("itemsize", 4)
-    return 3 * bn * itemsize + 2 * itemsize
+from repro.kernels.axpy_norm import kernel as axpy_kernel
 
 
 def _constrain(hw, shapes, block):
-    bn = max(int(block["block_n"]), hw.lane_count)
-    bn -= bn % hw.lane_count
+    tile = hw.sublane_count * hw.lane_count
+    bn = max(int(block["block_n"]), tile)
+    bn -= bn % tile
     return {"block_n": bn}
 
 
@@ -32,13 +26,15 @@ AXPY_NORM_SPEC = tuning.register_spec(
     tuning.TuningSpec(
         op="axpy_norm",
         params=("block_n",),
-        seed=lambda hw: {"block_n": hw.lane_count * hw.sublane_count * 4},
-        vmem_bytes=_vmem_bytes,
+        seed=lambda hw: {"block_n": hw.lane_count * hw.sublane_count * 32},
+        vmem_bytes=lambda shapes, block: axpy_kernel.vmem_bytes(
+            block["block_n"], shapes.get("itemsize", 4)
+        ),
         constrain=_constrain,
-        floors={"block_n": 128},
+        floors={"block_n": 1024},
         candidates=lambda hw, shapes: [
             {"block_n": hw.lane_count * hw.sublane_count * f}
-            for f in (1, 2, 4, 8)
+            for f in (8, 32, 128)
         ],
     )
 )
@@ -53,11 +49,7 @@ def _axpy_norm_skeleton(ex, alpha, x, y, *, variant: str):
     cfg = ex.launch_config(
         "axpy_norm", {"n": x.shape[0], "itemsize": x.dtype.itemsize}
     )
-    if not cfg.fits_vmem:
-        from repro.sparse.ops import _axpy_norm_xla
-
-        return _axpy_norm_xla(ex, alpha, x, y)
-    return axpy_norm_pallas(
+    return axpy_kernel.axpy_norm(
         alpha, x, y, block_n=cfg["block_n"], interpret=ex.interpret
     )
 

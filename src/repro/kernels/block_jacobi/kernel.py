@@ -1,11 +1,12 @@
 """Block-Jacobi apply Pallas TPU kernel.
 
-One grid step processes ``block_nb`` diagonal blocks: a
-``(block_nb, bs, bs)`` tile of inverted blocks and the matching
-``(block_nb, bs)`` tile of gathered vector segments, producing
-``(block_nb, bs)`` outputs.  The block batch axis is the only grid axis —
-each step's working set is independent, so there is no cross-step
-accumulation (unlike the SpMV kernels).
+``y[b] = inv_blocks[b] @ vp[b]`` for ``nb`` small ``(bs, bs)`` blocks.  The
+kernel works block-minor and lane-dense (:mod:`repro.kernels.lanes`): the
+inverted blocks as ``(bs, bs, rows, 128)`` and the vector segments as
+``(bs, rows, 128)``, block ``b`` at ``[..., b // 128, b % 128]``.  One block
+row is then ``bs`` elementwise multiply-adds of full vector tiles — no lane
+shuffles, and no 8-wide minor axis padded out to 128 lanes.  Each grid step
+covers ``block_nb`` blocks and is independent of the others.
 
 Mixed precision: ``inv_blocks`` may arrive in a reduced *storage* precision
 (bf16/fp16 — the adaptive block-Jacobi selection); the kernel upcasts inside
@@ -13,8 +14,8 @@ the body so the VMEM traffic pays the reduced footprint while the arithmetic
 stays in the vector's precision (arXiv:2006.16852's storage/arithmetic
 decoupling).
 
-Padding blocks (appended to round ``nb`` up to a ``block_nb`` multiple) are
-zero everywhere, contribute zero rows, and are sliced off by the wrapper.
+Padding blocks (appended to round ``nb`` up to a whole number of tiles) are
+zero everywhere, produce zero rows, and are sliced off by the wrapper.
 """
 
 from __future__ import annotations
@@ -25,11 +26,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import lanes
+
+
+def vmem_bytes(block_nb: int, bs: int, itemsize: int) -> int:
+    """Scoped VMEM of one launch: double-buffered inverted-block, segment and
+    output tiles (vectors in f32) plus the compiler's scratch."""
+    return 2 * block_nb * bs * (bs * itemsize + 2 * 4) + lanes.MOSAIC_SCRATCH_BYTES
+
 
 def _block_jacobi_kernel(inv_ref, v_ref, o_ref):
-    blocks = inv_ref[...].astype(o_ref.dtype)  # (block_nb, bs, bs)
-    v = v_ref[...].astype(o_ref.dtype)  # (block_nb, bs)
-    o_ref[...] = jnp.sum(blocks * v[:, None, :], axis=-1)
+    bs = v_ref.shape[0]
+    acc = jnp.zeros(o_ref.shape, o_ref.dtype)  # (bs, block_rows, 128)
+    for j in range(bs):
+        acc += inv_ref[:, j].astype(o_ref.dtype) * v_ref[j][None]
+    o_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("block_nb", "interpret"))
@@ -37,27 +48,28 @@ def block_jacobi_apply(
     inv_blocks: jax.Array,
     vp: jax.Array,
     *,
-    block_nb: int = 128,
+    block_nb: int = 8192,
     interpret: bool = False,
 ) -> jax.Array:
     """y[b] = inv_blocks[b] @ vp[b] for (nb, bs, bs) blocks, (nb, bs) segments."""
     nb, bs, _ = inv_blocks.shape
     out_dtype = vp.dtype
-    block_nb = max(min(block_nb, nb), 1)
-    pnb = ((nb + block_nb - 1) // block_nb) * block_nb
-    if pnb != nb:
-        inv_blocks = jnp.pad(inv_blocks, ((0, pnb - nb), (0, 0), (0, 0)))
-        vp = jnp.pad(vp, ((0, pnb - nb), (0, 0)))
-
+    rows, block_rows = lanes.row_tiling(nb, block_nb, inv_blocks.dtype, out_dtype)
+    inv_t = lanes.to_rows(jnp.moveaxis(inv_blocks, 0, -1), rows)  # (bs, bs, R, 128)
+    v_t = lanes.to_rows(vp.T, rows)  # (bs, R, 128)
+    tile = (block_rows, lanes.LANES)
     out = pl.pallas_call(
         _block_jacobi_kernel,
-        grid=(pnb // block_nb,),
+        grid=(rows // block_rows,),
         in_specs=[
-            pl.BlockSpec((block_nb, bs, bs), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_nb, bs), lambda i: (i, 0)),
+            pl.BlockSpec((bs, bs) + tile, lambda i: (0, 0, i, 0)),
+            pl.BlockSpec((bs,) + tile, lambda i: (0, i, 0)),
         ],
-        out_specs=pl.BlockSpec((block_nb, bs), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((pnb, bs), out_dtype),
+        out_specs=pl.BlockSpec((bs,) + tile, lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bs, rows, lanes.LANES), out_dtype),
+        compiler_params=lanes.compiler_params(
+            vmem_bytes(block_rows * lanes.LANES, bs, inv_blocks.dtype.itemsize)
+        ),
         interpret=interpret,
-    )(inv_blocks, vp)
-    return out[:nb]
+    )(inv_t, v_t)
+    return lanes.from_rows(out, nb).T

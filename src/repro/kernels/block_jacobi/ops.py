@@ -15,21 +15,15 @@ Three kernel spaces:
 from __future__ import annotations
 
 from repro.core import registry, tuning
-from repro.kernels.block_jacobi.kernel import block_jacobi_apply as bj_pallas
+from repro.kernels.block_jacobi import kernel as bj_kernel
 from repro.kernels.block_jacobi.ref import block_jacobi_apply_ref
 
 
-def _vmem_bytes(shapes, block) -> int:
-    # inv-block tile (storage itemsize) + gathered segments and outputs (f32)
-    bnb = block["block_nb"]
-    bs = shapes.get("bs", 8)
-    itemsize = shapes.get("itemsize", 4)
-    return bnb * bs * bs * itemsize + 2 * bnb * bs * 4
-
-
 def _constrain(hw, shapes, block):
-    bnb = max(int(block["block_nb"]), hw.sublane_count)
-    bnb -= bnb % hw.sublane_count
+    # whole (sublane x lane) tiles of blocks: the kernel is block-minor
+    tile = hw.sublane_count * hw.lane_count
+    bnb = max(int(block["block_nb"]), tile)
+    bnb -= bnb % tile
     return {"block_nb": bnb}
 
 
@@ -37,16 +31,14 @@ BLOCK_JACOBI_SPEC = tuning.register_spec(
     tuning.TuningSpec(
         op="block_jacobi",
         params=("block_nb",),
-        seed=lambda hw: {
-            # blocks are subwarp-sized (bs <= subgroup width), so a generous
-            # batch tile keeps the VPU fed without pressuring VMEM
-            "block_nb": max(hw.sublane_count * 16, 8),
-        },
-        vmem_bytes=_vmem_bytes,
+        seed=lambda hw: {"block_nb": hw.sublane_count * hw.lane_count * 4},
+        vmem_bytes=lambda shapes, block: bj_kernel.vmem_bytes(
+            block["block_nb"], shapes.get("bs", 8), shapes.get("itemsize", 4)
+        ),
         constrain=_constrain,
-        floors={"block_nb": 8},
+        floors={"block_nb": 1024},
         candidates=lambda hw, shapes: [
-            {"block_nb": hw.sublane_count * f} for f in (8, 16, 32, 64)
+            {"block_nb": hw.sublane_count * hw.lane_count * f} for f in (1, 4, 16)
         ],
     )
 )
@@ -63,10 +55,7 @@ def _block_jacobi_skeleton(ex, inv_blocks, vp, *, variant: str):
             "itemsize": inv_blocks.dtype.itemsize,
         },
     )
-    if not cfg.fits_vmem:
-        # no tile fits this target's budget — portable formulation instead
-        return block_jacobi_apply_ref(inv_blocks, vp)
-    return bj_pallas(
+    return bj_kernel.block_jacobi_apply(
         inv_blocks, vp, block_nb=cfg["block_nb"], interpret=ex.interpret
     )
 

@@ -2,17 +2,9 @@
 
 The arXiv:2011.08879 fusion: Krylov iterations follow every SpMV with a dot
 product against the same vectors (``p·Ap`` in CG, ``r̂·v`` in BiCGSTAB), and
-launching the dot separately re-streams ``y`` through HBM.  This kernel emits
-the partial reduction in the same pass: each (block_m, block_k) tile adds its
-row partials into the revisited y block AND adds ``Σ_r w_r · partial_r`` into
-a scalar accumulator block — both well-defined because TPU grids iterate
-sequentially (same read-modify-write idiom as :mod:`repro.kernels.spmv_ell`).
-
-The dot is linear in the tile contributions
-(``w·y = Σ_{i,j} Σ_{r∈tile_i} w_r partial(r, j)``), so accumulation order only
-changes rounding, never the result's definition.  ``w`` rides in one
-(block_m,) tile per row-block; padding rows carry w = 0 and contribute
-nothing.
+launching the dot separately re-streams ``y`` through HBM.  The ELL SpMV
+kernel (:mod:`repro.kernels.spmv_ell.kernel`) emits the partial reduction in
+the same pass when given ``w``; this module is its fused entry point.
 """
 
 from __future__ import annotations
@@ -20,44 +12,12 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.core import coop
-
-
-def _spmv_dot_ell_kernel(
-    cols_ref, vals_ref, x_ref, w_ref, o_ref, d_ref, *, use_coop: bool
-):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init_y():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when((i == 0) & (j == 0))
-    def _init_dot():
-        d_ref[...] = jnp.zeros_like(d_ref)
-
-    vals = vals_ref[...]  # (block_m, block_k)
-    cols = cols_ref[...]
-    x = x_ref[...]  # (n,)
-    gathered = x[cols]
-    prod = vals * gathered
-    if use_coop:
-        row_sum = coop.subgroup(prod, prod.shape[-1]).sum()[..., :1]
-    else:
-        row_sum = jnp.sum(prod, axis=-1, keepdims=True)
-    o_ref[...] += row_sum.astype(o_ref.dtype)
-    # the fused reduction: this tile's contribution to w·y
-    w = w_ref[...]  # (block_m,)
-    d_ref[0, 0] += jnp.sum(w * row_sum[:, 0]).astype(d_ref.dtype)
+from repro.kernels.spmv_ell.kernel import ell_apply
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("block_m", "block_k", "use_coop", "interpret"),
+    jax.jit, static_argnames=("block_m", "block_k", "interpret")
 )
 def spmv_dot_ell(
     col_idx: jax.Array,
@@ -65,44 +25,12 @@ def spmv_dot_ell(
     x: jax.Array,
     w: jax.Array,
     *,
-    block_m: int = 256,
-    block_k: int = 128,
-    use_coop: bool = True,
+    block_m: int = 8192,
+    block_k: int = 32,
     interpret: bool = False,
 ):
     """(y, w·y) = (A @ x, dot) for ELL-format A of shape (m, k), one pass."""
-    m, k = values.shape
-    n = x.shape[0]
-
-    block_m = max(min(block_m, m), 1)
-    block_k = max(min(block_k, k), 1)
-    pm = ((m + block_m - 1) // block_m) * block_m
-    pk = ((k + block_k - 1) // block_k) * block_k
-    if (pm, pk) != (m, k):
-        col_idx = jnp.pad(col_idx, ((0, pm - m), (0, pk - k)))
-        values = jnp.pad(values, ((0, pm - m), (0, pk - k)))
-    if pm != m:
-        # padding rows must not contribute to the dot
-        w = jnp.pad(w, (0, pm - m))
-    use_coop = use_coop and (block_k & (block_k - 1) == 0)
-
-    y, d = pl.pallas_call(
-        functools.partial(_spmv_dot_ell_kernel, use_coop=use_coop),
-        grid=(pm // block_m, pk // block_k),
-        in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, j: (i, j)),
-            pl.BlockSpec((block_m, block_k), lambda i, j: (i, j)),
-            pl.BlockSpec((n,), lambda i, j: (0,)),
-            pl.BlockSpec((block_m,), lambda i, j: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_m, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((pm, 1), values.dtype),
-            jax.ShapeDtypeStruct((1, 1), values.dtype),
-        ],
-        interpret=interpret,
-    )(col_idx, values, x, w)
-    return y[:m, 0], d[0, 0]
+    return ell_apply(
+        col_idx, values, x, w,
+        block_m=block_m, block_k=block_k, interpret=interpret,
+    )

@@ -4,7 +4,8 @@ The reference/xla spaces live in :mod:`repro.sparse.ops` (they compute the
 unfused SpMV followed by a vdot — bitwise identical to the unfused path, which
 is what the fallback-parity tests pin).  This module binds the hardware-native
 fused skeleton; its tile geometry resolves through the launch-configuration
-table like every kernel family.
+table with the same alignment rules as the plain ELL SpMV, and like it the
+binding never switches to another space.
 
 ``spmv_dot_csr`` has no pallas space — mirroring the base ``spmv_csr``
 coverage (the repo carries no hand-written CSR SpMV kernel); pallas executors
@@ -17,41 +18,23 @@ from __future__ import annotations
 
 from repro.core import registry, tuning
 from repro.kernels.spmv_dot.kernel import spmv_dot_ell as spmv_dot_ell_pallas
+from repro.kernels.spmv_ell.ops import (
+    ell_candidates,
+    ell_constrain,
+    ell_seed,
+    ell_vmem_bytes,
+)
 from repro.sparse.formats import Ell
-
-
-def _vmem_bytes(shapes, block) -> int:
-    # cols (int32) + values tiles, x fully VMEM-resident, w + y column tiles,
-    # one scalar accumulator
-    bm, bk = block["block_m"], block["block_k"]
-    n = shapes.get("n", 0)
-    itemsize = shapes.get("itemsize", 4)
-    return bm * bk * (itemsize + 4) + n * itemsize + 2 * bm * itemsize + itemsize
-
-
-def _constrain(hw, shapes, block):
-    bm = max(int(block["block_m"]), hw.sublane_count)
-    bm -= bm % hw.sublane_count
-    bk = tuning.prev_pow2(max(int(block["block_k"]), 8))
-    return {"block_m": bm, "block_k": bk}
-
 
 SPMV_DOT_SPEC = tuning.register_spec(
     tuning.TuningSpec(
         op="spmv_dot",
         params=("block_m", "block_k"),
-        seed=lambda hw: {
-            "block_m": max(hw.sublane_count * 32, 8),
-            "block_k": hw.lane_count,
-        },
-        vmem_bytes=_vmem_bytes,
-        constrain=_constrain,
-        floors={"block_m": 8, "block_k": 8},
-        candidates=lambda hw, shapes: [
-            {"block_m": bm, "block_k": bk}
-            for bm in (hw.sublane_count * 16, hw.sublane_count * 32, hw.sublane_count * 64)
-            for bk in (hw.lane_count // 2, hw.lane_count)
-        ],
+        seed=ell_seed,
+        vmem_bytes=lambda shapes, block: ell_vmem_bytes(shapes, block, dot=True),
+        constrain=ell_constrain,
+        floors={"block_m": 1024, "block_k": 1},
+        candidates=ell_candidates,
     )
 )
 
@@ -68,10 +51,6 @@ def _spmv_dot_ell_skeleton(ex, A: Ell, x, w, *, variant: str):
             "itemsize": x.dtype.itemsize,
         },
     )
-    if not cfg.fits_vmem:
-        from repro.sparse.ops import _spmv_dot_ell_xla
-
-        return _spmv_dot_ell_xla(ex, A, x, w)
     return spmv_dot_ell_pallas(
         A.col_idx,
         A.values,
@@ -79,7 +58,6 @@ def _spmv_dot_ell_skeleton(ex, A: Ell, x, w, *, variant: str):
         w,
         block_m=cfg["block_m"],
         block_k=cfg["block_k"],
-        use_coop=True,
         interpret=ex.interpret,
     )
 

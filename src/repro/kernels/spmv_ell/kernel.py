@@ -1,89 +1,144 @@
-"""ELL SpMV Pallas TPU kernel.
+"""ELL SpMV Pallas TPU kernel, with an optional fused dot epilogue.
 
-Layout (DESIGN.md §2): row-major (m, max_nnz) blocks — a (block_m, block_k)
-VMEM tile per grid step, with ``x`` held entirely in VMEM (the benchmark
-matrices keep n*4B well under the VMEM budget; the wrapper enforces this via
-the executor's ``vmem_limit_bytes``).
+XLA gathers ``x[col_idx]`` outside the kernel; the kernel streams the values
+and the gathered entries and reduces each row.  Both operands are laid out
+slot-major and lane-dense — ``(k, rows, 128)``, row ``r`` of the matrix at
+``[:, r // 128, r % 128]`` (:mod:`repro.kernels.lanes`) — so the row
+reduction is an elementwise sum over the leading ``k`` axis and the output
+``y`` is a lane-dense ``(rows, 128)`` view.  This is Ginkgo's column-major
+ELL storage: consecutive rows sit in consecutive lanes.
 
-The per-row reduction over the k axis uses the cooperative-group butterfly
-(:mod:`repro.core.coop`) when ``block_k`` is the lane axis — Ginkgo's
-"subwarp per row" ELL strategy mapped to lane-segment collectives.
+Grid = (row blocks, k blocks), k innermost; partial sums accumulate in the
+revisited output block (TPU grids iterate in order, so the read-modify-write
+across k steps is well-defined).
 
-Grid = (m/block_m, k/block_k), k innermost; partial sums accumulate in the
-revisited output block (TPU grids iterate sequentially, so read-modify-write
-on o_ref across k steps is well-defined).
+With ``w`` given, each step also adds ``Σ_r w_r · partial_r`` into a
+``(1, 128)`` accumulator revisited by every step — the apply-with-reduction
+fusion of arXiv:2011.08879 (``p·Ap`` in CG): the dot is linear in the tile
+contributions, so accumulation order only changes rounding.  Padding rows
+carry ``w = 0`` and value 0.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core import coop
+from repro.kernels import lanes
 
 
-def _spmv_ell_kernel(cols_ref, vals_ref, x_ref, o_ref, *, use_coop: bool):
-    j = pl.program_id(1)
+def vmem_bytes(block_m: int, block_k: int, itemsize: int, *, dot: bool) -> int:
+    """Scoped VMEM of one launch: double-buffered value and gathered-x slabs,
+    the y block (and the w block with ``dot``), the dot accumulator and the
+    compiler's scratch."""
+    slabs = 2 * 2 * block_k * block_m * itemsize
+    vectors = 2 * (2 if dot else 1) * block_m * itemsize
+    return slabs + vectors + lanes.LANES * 4 + lanes.MOSAIC_SCRATCH_BYTES
+
+
+def _ell_kernel(vals_ref, xg_ref, *refs, dot: bool):
+    i, j = pl.program_id(0), pl.program_id(1)
+    if dot:
+        w_ref, y_ref, d_ref = refs
+    else:
+        (y_ref,) = refs
 
     @pl.when(j == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    def _init_y():
+        y_ref[...] = jnp.zeros_like(y_ref)
 
-    vals = vals_ref[...]  # (block_m, block_k)
-    cols = cols_ref[...]
-    x = x_ref[...]  # (n,)
-    gathered = x[cols]  # gather along lanes (see DESIGN.md lowering note)
-    prod = vals * gathered
-    if use_coop:
-        # Ginkgo ELL: one subwarp reduces one row; here the "subwarp" is the
-        # full lane segment of the row tile (butterfly shfl_xor reduction).
-        row_sum = coop.subgroup(prod, prod.shape[-1]).sum()[..., :1]
-    else:
-        row_sum = jnp.sum(prod, axis=-1, keepdims=True)
-    o_ref[...] += row_sum.astype(o_ref.dtype)
+    acc = jnp.promote_types(y_ref.dtype, jnp.float32)
+    part = jnp.sum(
+        vals_ref[...].astype(acc) * xg_ref[...].astype(acc), axis=0
+    )  # (block_rows, 128)
+    y_ref[...] += part.astype(y_ref.dtype)
+    if dot:
+
+        @pl.when((i == 0) & (j == 0))
+        def _init_dot():
+            d_ref[...] = jnp.zeros_like(d_ref)
+
+        d_ref[...] += jnp.sum(
+            w_ref[...].astype(acc) * part, axis=0, keepdims=True
+        ).astype(d_ref.dtype)
+
+
+def ell_apply(
+    col_idx: jax.Array,
+    values: jax.Array,
+    x: jax.Array,
+    w: Optional[jax.Array] = None,
+    *,
+    block_m: int,
+    block_k: int,
+    interpret: bool,
+):
+    """``y = A @ x`` (and ``w · y`` when ``w`` is given) for ELL-format A."""
+    m, k = values.shape
+    dtype = jnp.result_type(values.dtype, x.dtype)
+    dot = w is not None
+    rows, block_rows = lanes.row_tiling(m, block_m, values.dtype, dtype)
+    block_k = max(min(block_k, k), 1)
+    pk = pl.cdiv(k, block_k) * block_k
+    # slot-major, lane-dense operands; padding is (col 0, value 0)
+    cols_t = lanes.to_rows(col_idx.T, rows)
+    vals_t = lanes.to_rows(values.T, rows)
+    if pk != k:
+        cols_t = jnp.pad(cols_t, ((0, pk - k), (0, 0), (0, 0)))
+        vals_t = jnp.pad(vals_t, ((0, pk - k), (0, 0), (0, 0)))
+    xg = x[cols_t]  # the gather stays in XLA
+
+    slab = pl.BlockSpec((block_k, block_rows, lanes.LANES), lambda i, j: (j, i, 0))
+    vec = pl.BlockSpec((block_rows, lanes.LANES), lambda i, j: (i, 0))
+    in_specs = [slab, slab]
+    operands = [vals_t, xg]
+    out_specs = [vec]
+    out_shape = [jax.ShapeDtypeStruct((rows, lanes.LANES), dtype)]
+    if dot:
+        in_specs.append(vec)
+        operands.append(lanes.to_rows(w.astype(dtype), rows))
+        out_specs.append(pl.BlockSpec((1, lanes.LANES), lambda i, j: (0, 0)))
+        acc = jnp.promote_types(dtype, jnp.float32)
+        out_shape.append(jax.ShapeDtypeStruct((1, lanes.LANES), acc))
+    itemsize = max(jnp.dtype(values.dtype).itemsize, jnp.dtype(dtype).itemsize)
+    outs = pl.pallas_call(
+        functools.partial(_ell_kernel, dot=dot),
+        grid=(rows // block_rows, pk // block_k),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=lanes.compiler_params(
+            vmem_bytes(block_rows * lanes.LANES, block_k, itemsize, dot=dot)
+        ),
+        interpret=interpret,
+    )(*operands)
+    y = lanes.from_rows(outs[0], m)
+    if dot:
+        return y, jnp.sum(outs[1]).astype(dtype)
+    return y
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("block_m", "block_k", "use_coop", "interpret"),
+    jax.jit, static_argnames=("block_m", "block_k", "interpret")
 )
 def spmv_ell(
     col_idx: jax.Array,
     values: jax.Array,
     x: jax.Array,
     *,
-    block_m: int = 256,
-    block_k: int = 128,
-    use_coop: bool = True,
+    block_m: int = 8192,
+    block_k: int = 32,
     interpret: bool = False,
 ) -> jax.Array:
-    """y = A @ x for ELL-format A given as (col_idx, values) of shape (m, k)."""
-    m, k = values.shape
-    n = x.shape[0]
+    """y = A @ x for ELL-format A given as (col_idx, values) of shape (m, k).
 
-    block_m = max(min(block_m, m), 1)
-    block_k = max(min(block_k, k), 1)
-    # pad m and k to block multiples (padding: col 0, value 0 — contributes 0)
-    pm = ((m + block_m - 1) // block_m) * block_m
-    pk = ((k + block_k - 1) // block_k) * block_k
-    if (pm, pk) != (m, k):
-        col_idx = jnp.pad(col_idx, ((0, pm - m), (0, pk - k)))
-        values = jnp.pad(values, ((0, pm - m), (0, pk - k)))
-    use_coop = use_coop and (block_k & (block_k - 1) == 0)
-
-    out = pl.pallas_call(
-        functools.partial(_spmv_ell_kernel, use_coop=use_coop),
-        grid=(pm // block_m, pk // block_k),
-        in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, j: (i, j)),
-            pl.BlockSpec((block_m, block_k), lambda i, j: (i, j)),
-            pl.BlockSpec((n,), lambda i, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block_m, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((pm, 1), values.dtype),
-        interpret=interpret,
-    )(col_idx, values, x)
-    return out[:m, 0]
+    ``block_m`` rows (a multiple of 1024 = 8 sublanes x 128 lanes, rounded
+    down) and ``block_k`` ELL slots are streamed per grid step.
+    """
+    return ell_apply(
+        col_idx, values, x, block_m=block_m, block_k=block_k, interpret=interpret
+    )

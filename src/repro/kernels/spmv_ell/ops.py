@@ -1,49 +1,59 @@
 """Registry binding: the Pallas ELL SpMV serves operation ``spmv_ell``.
 
 The reference/xla spaces live in :mod:`repro.sparse.ops`; this module binds the
-hardware-native skeleton, whose (block_m, block_k) tile and x-residency
-feasibility both come from the launch-configuration table.
+hardware-native skeleton, whose (block_m, block_k) tile comes from the
+launch-configuration table.  The kernel keeps no vector resident in VMEM, so
+every shape runs the Pallas kernel — there is no size at which the binding
+switches to another space.
 """
 
 from __future__ import annotations
 
 from repro.core import registry, tuning
-from repro.kernels.spmv_ell.kernel import spmv_ell as spmv_ell_pallas
+from repro.kernels.spmv_ell import kernel as ell_kernel
 from repro.sparse.formats import Ell
 
 
-def _vmem_bytes(shapes, block) -> int:
-    # cols (int32) + values tiles, x fully VMEM-resident, output column
-    bm, bk = block["block_m"], block["block_k"]
-    n = shapes.get("n", 0)
-    itemsize = shapes.get("itemsize", 4)
-    return bm * bk * (itemsize + 4) + n * itemsize + bm * itemsize
+def ell_constrain(hw, shapes, block):
+    """Row blocks of whole (sublane x lane) tiles; at least one ELL slot."""
+    tile = hw.sublane_count * hw.lane_count
+    bm = max(int(block["block_m"]), tile)
+    bm -= bm % tile
+    return {"block_m": bm, "block_k": max(int(block["block_k"]), 1)}
 
 
-def _constrain(hw, shapes, block):
-    bm = max(int(block["block_m"]), hw.sublane_count)
-    bm -= bm % hw.sublane_count
-    # power-of-two lanes keep the coop butterfly legal
-    bk = tuning.prev_pow2(max(int(block["block_k"]), 8))
-    return {"block_m": bm, "block_k": bk}
+def ell_seed(hw):
+    return {
+        "block_m": hw.sublane_count * hw.lane_count * 8,
+        "block_k": hw.sublane_count * 4,
+    }
+
+
+def ell_vmem_bytes(shapes, block, *, dot: bool) -> int:
+    bk = min(block["block_k"], shapes.get("k", block["block_k"]))
+    return ell_kernel.vmem_bytes(
+        block["block_m"], bk, shapes.get("itemsize", 4), dot=dot
+    )
+
+
+def ell_candidates(hw, shapes):
+    tile = hw.sublane_count * hw.lane_count
+    return [
+        {"block_m": tile * f, "block_k": bk}
+        for f in (4, 8, 32)
+        for bk in (hw.sublane_count, hw.sublane_count * 4)
+    ]
 
 
 ELL_SPEC = tuning.register_spec(
     tuning.TuningSpec(
         op="spmv_ell",
         params=("block_m", "block_k"),
-        seed=lambda hw: {
-            "block_m": max(hw.sublane_count * 32, 8),
-            "block_k": hw.lane_count,
-        },
-        vmem_bytes=_vmem_bytes,
-        constrain=_constrain,
-        floors={"block_m": 8, "block_k": 8},
-        candidates=lambda hw, shapes: [
-            {"block_m": bm, "block_k": bk}
-            for bm in (hw.sublane_count * 16, hw.sublane_count * 32, hw.sublane_count * 64)
-            for bk in (hw.lane_count // 2, hw.lane_count)
-        ],
+        seed=ell_seed,
+        vmem_bytes=lambda shapes, block: ell_vmem_bytes(shapes, block, dot=False),
+        constrain=ell_constrain,
+        floors={"block_m": 1024, "block_k": 1},
+        candidates=ell_candidates,
     )
 )
 
@@ -60,20 +70,12 @@ def _spmv_ell_skeleton(ex, A: Ell, x, *, variant: str):
             "itemsize": x.dtype.itemsize,
         },
     )
-    if not cfg.fits_vmem:
-        # x would not fit the VMEM residency strategy on this target —
-        # fall through to the XLA kernel (Ginkgo: executor picks the kernel
-        # variant suited to the problem granularity).
-        from repro.sparse.ops import _spmv_ell_xla
-
-        return _spmv_ell_xla(ex, A, x)
-    return spmv_ell_pallas(
+    return ell_kernel.spmv_ell(
         A.col_idx,
         A.values,
         x,
         block_m=cfg["block_m"],
         block_k=cfg["block_k"],
-        use_coop=True,
         interpret=ex.interpret,
     )
 

@@ -25,6 +25,7 @@ import time
 import numpy as np
 
 from repro.core import make_executor, use_executor
+from repro.launch.cache import use_compile_cache
 from repro.observability import trace
 from repro.precond import make_preconditioner
 from repro.solvers.common import Stop
@@ -91,6 +92,7 @@ def run_amg_check(
 
 
 def main(argv=None) -> int:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small CI run (64x64 grid, 3x gate)")

@@ -26,8 +26,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import batch as batch_lib
 from repro.core import make_executor, use_executor
+from repro.launch.cache import use_compile_cache
 from repro.observability import trace
-from repro.launch.mesh import compat_make_mesh
 from repro.solvers.common import Stop
 
 __all__ = ["build_batch", "shard_batch", "solve_batch", "main"]
@@ -116,6 +116,7 @@ def report(res, xstar, wall: float) -> None:
 
 
 def main(argv=None) -> int:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small end-to-end run (64 systems)")
@@ -140,7 +141,9 @@ def main(argv=None) -> int:
     # the data axis carries the batch; pad nb up so it divides evenly
     if nb % ndev:
         nb += ndev - nb % ndev
-    mesh = compat_make_mesh((ndev,), ("data",))
+    mesh = jax.make_mesh(
+        (ndev,), ("data",), axis_types=(jax.sharding.AxisType.Auto,)
+    )
     print(f"batch_solve: {nb} x ({n}x{n}) {args.fmt} systems, "
           f"{args.solver}/{args.precond}, mesh data={ndev}, "
           f"executor={args.executor}")

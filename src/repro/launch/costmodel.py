@@ -233,15 +233,3 @@ def function_cost(fn, *args, **kwargs) -> Dict[str, float]:
         "transcendentals": c.transcendentals,
     }
 
-
-def hlo_cost_analysis(compiled) -> Dict[str, float]:
-    """Version-tolerant ``compiled.cost_analysis()``.
-
-    jax <= 0.4.x returns a one-element list of dicts (per device assignment);
-    newer jax returns the dict directly.  Either way: a flat dict (possibly
-    empty).
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost or {})

@@ -1,7 +1,7 @@
 """Distributed-solve driver: one Krylov solve sharded across the devices.
 
-The distributed twin of ``repro.launch.batch_solve``: build a sparse SPD (or
-perturbed nonsymmetric) system, row-partition it over the available devices
+The distributed twin of ``repro.launch.batch_solve``: build a gallery system
+(:func:`build_system`), row-partition it over the available devices
 (:class:`repro.distributed.Partition` + :class:`DistCsr`/:class:`DistEll`),
 and hand it to the UNCHANGED solver entry point — ``krylov.cg`` notices the
 distributed operand and runs the whole iteration under ``shard_map`` (local
@@ -10,8 +10,8 @@ single-device solve: same iteration count (±1), matching solution.
 
 Usage:
     python -m repro.launch.dist_solve --smoke
-    python -m repro.launch.dist_solve --n 4096 --solver cg --format csr \
-        --precond block_jacobi --shards 8 --executor xla
+    python -m repro.launch.dist_solve --n-side 64 --solver cg --format ell \
+        --precond jacobi --shards 4 --executor pallas
 
 On a CPU host, force virtual devices first:
     XLA_FLAGS=--xla_force_host_platform_device_count=8
@@ -29,45 +29,48 @@ import jax.numpy as jnp
 from repro import sparse
 from repro.core import make_executor, use_executor
 from repro.distributed import DistCsr, DistEll, Partition
+from repro.launch.cache import use_compile_cache
 from repro.observability import trace
 from repro.solvers import krylov
 from repro.solvers.common import Stop
+from repro.sparse import gallery
 
-__all__ = ["build_system", "main"]
+__all__ = ["build_system", "csr_matvec_f64", "main"]
 
 
-def build_system(n: int, *, nonsym: bool = False, seed: int = 0):
-    """2-D five-point stencil on the largest square grid fitting ``n`` rows,
-    padded with a shifted-diagonal tail so any ``n`` works; SPD by
-    construction, optionally perturbed strictly-upper for the nonsymmetric
-    solvers."""
-    rng = np.random.default_rng(seed)
-    side = max(1, int(np.sqrt(n)))
-    a = np.zeros((n, n), np.float32)
-    idx = np.arange(n)
-    a[idx, idx] = 4.0
-    for r in range(n):
-        i, j = divmod(r, side)
-        if j > 0:
-            a[r, r - 1] = -1.0
-        if j < side - 1 and r + 1 < n:
-            a[r, r + 1] = -1.0
-        if i > 0:
-            a[r, r - side] = -1.0
-        if r + side < n:
-            a[r, r + side] = -1.0
+def csr_matvec_f64(host_csr, x: np.ndarray) -> np.ndarray:
+    """``A @ x`` in float64 on the host from gallery CSR arrays."""
+    indptr, indices, values, (m, _) = host_csr
+    rows = np.repeat(np.arange(m), np.diff(indptr))
+    prod = values.astype(np.float64) * np.asarray(x, np.float64)[indices]
+    return np.bincount(rows, weights=prod, minlength=m)
+
+
+def build_system(n_side: int, *, nonsym: bool = False, seed: int = 0):
+    """The gallery system the solve drivers and the chip smoke test share.
+
+    SPD: the 7-point 3-D Poisson stencil on an ``n_side``³ grid (HPCG's
+    problem class; ``n_side=128`` is 2,097,152 rows).  ``nonsym``: upwind
+    convection-diffusion on an ``n_side``² grid, for the nonsymmetric
+    solvers.  Returns ``(host_csr, xstar, b)`` with ``xstar`` drawn from
+    ``seed`` and ``b = A @ xstar`` (float32).
+    """
     if nonsym:
-        mask = rng.random((n, n)) < min(1.0, 8.0 / n)
-        a += np.triu(np.where(mask, 0.05, 0.0), 1).astype(np.float32)
-    xstar = rng.normal(size=n).astype(np.float32)
-    return a, xstar, (a @ xstar).astype(np.float32)
+        host = gallery.convection_diffusion_2d(n_side)
+    else:
+        host = gallery.poisson_3d(n_side)
+    n = host[3][0]
+    xstar = np.random.default_rng(seed).standard_normal(n).astype(np.float32)
+    return host, xstar, csr_matvec_f64(host, xstar).astype(np.float32)
 
 
 def main(argv=None) -> int:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small end-to-end run with parity check")
-    ap.add_argument("--n", type=int, default=1024, help="global rows")
+    ap.add_argument("--n-side", type=int, default=16,
+                    help="grid side (3-D Poisson: n_side^3 rows)")
     ap.add_argument("--solver", default="cg",
                     choices=("cg", "fcg", "bicgstab", "cgs", "gmres"))
     ap.add_argument("--format", default="csr", choices=("csr", "ell"),
@@ -84,7 +87,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     trace.enable_from_args(args)
 
-    n = 225 if args.smoke else args.n
+    n_side = 6 if args.smoke else args.n_side
     ndev = len(jax.devices())
     shards = args.shards or ndev
     if shards > ndev:
@@ -92,8 +95,11 @@ def main(argv=None) -> int:
         shards = ndev
 
     nonsym = args.solver in ("bicgstab", "cgs", "gmres")
-    a, xstar, b = build_system(n, nonsym=nonsym)
-    A = sparse.csr_from_dense(a) if args.fmt == "csr" else sparse.ell_from_dense(a)
+    host, xstar, b = build_system(n_side, nonsym=nonsym)
+    n = host[3][0]
+    A = sparse.csr_from_arrays(*host)
+    if args.fmt == "ell":
+        A = sparse.ell_from_csr_host(*host)
     part = Partition.uniform(n, shards)
     dist_cls = DistCsr if args.fmt == "csr" else DistEll
     Ad = dist_cls.from_matrix(A, part)

@@ -26,31 +26,33 @@ Usage:
 """
 
 import argparse
-from repro.observability import trace
 import dataclasses
 import functools
 import json
 import re
 import time
-from typing import Dict, Optional
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs import SHAPES, cells, get_config, ARCH_IDS
+from repro.core.params import TPU_V5E
 from repro.distributed import sharding as shd
 from repro.launch import costmodel
 from repro.launch import steps as steps_lib
-from repro.launch.mesh import make_production_mesh, use_mesh as mesh_lib_use_mesh
+from repro.launch.cache import use_compile_cache
+from repro.launch.mesh import make_production_mesh
 from repro.models import lm
+from repro.observability import trace
 from repro.optim import adamw, warmup_cosine_schedule
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..", "experiments", "dryrun")
 
 # per-chip hardware constants (TPU v5e) for the roofline terms
-PEAK_FLOPS = 197e12  # bf16
-HBM_BW = 819e9
-ICI_BW = 50e9
+PEAK_FLOPS = TPU_V5E.peak_flops_bf16
+HBM_BW = TPU_V5E.hbm_bandwidth
+ICI_BW = TPU_V5E.ici_bandwidth
 
 _COLLECTIVE_RE = re.compile(
     r"=\s*(?P<out>\([^)]*\)|\S+)\s+"
@@ -199,20 +201,6 @@ def build_cell(arch: str, shape_name: str, *, multi_pod: bool, zero: str = "zero
     return fn, raw, args, mesh, cfg, shape
 
 
-def _peak_bytes(mem) -> Optional[float]:
-    """Peak device memory: the direct stat on newer jax, else the
-    argument+output+temp sum older CompiledMemoryStats exposes."""
-    peak = getattr(mem, "peak_memory_in_bytes", None)
-    if peak is not None:
-        return float(peak)
-    parts = [
-        getattr(mem, a, 0) or 0
-        for a in ("argument_size_in_bytes", "output_size_in_bytes",
-                  "temp_size_in_bytes")
-    ]
-    return float(sum(parts)) if any(parts) else None
-
-
 def run_cell(arch: str, shape_name: str, *, multi_pod: bool, zero: str = "zero1",
              attn: str = "chunked", sp: bool = True, capacity: float = None,
              remat: str = "block", moe_dispatch: str = "gather",
@@ -224,7 +212,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, zero: str = "zero1"
         capacity=capacity, remat=remat, moe_dispatch=moe_dispatch,
     )
     n_chips = mesh.size
-    with mesh_lib_use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         t_lower = time.perf_counter() - t0
         compiled = lowered.compile()
@@ -240,7 +228,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, zero: str = "zero1"
                 logical_flash = costmodel.function_cost(raw_fn, *args)
 
     mem = compiled.memory_analysis()
-    cost = costmodel.hlo_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     census = collective_census(hlo)
 
@@ -281,10 +269,10 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, zero: str = "zero1"
             "collective_bytes_wire": coll_bytes,
         },
         "memory_analysis": {
-            "argument_bytes": getattr(mem, "argument_size_in_bytes", None),
-            "output_bytes": getattr(mem, "output_size_in_bytes", None),
-            "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
-            "peak_bytes": _peak_bytes(mem),
+            "argument_bytes": mem.argument_size_in_bytes,
+            "output_bytes": mem.output_size_in_bytes,
+            "temp_bytes": mem.temp_size_in_bytes,
+            "peak_bytes": float(mem.peak_memory_in_bytes),
         },
         "collectives": census,
         "roofline": {
@@ -336,6 +324,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, zero: str = "zero1"
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -206,10 +206,11 @@ def _cmd_solve(args) -> int:
     if args.trace:
         trace.enable(args.trace)
 
-    n = 225 if args.smoke else args.n
+    n_side = 6 if args.smoke else args.n_side
     nonsym = args.solver in ("bicgstab", "cgs", "gmres")
-    a, xstar, b = build_system(n, nonsym=nonsym)
-    A = sparse.csr_from_dense(a)
+    host, xstar, b = build_system(n_side, nonsym=nonsym)
+    n = host[3][0]
+    A = sparse.csr_from_arrays(*host)
     stop = Stop(max_iters=args.max_iters, reduction_factor=args.tol)
     fn = getattr(krylov, args.solver)
 
@@ -243,6 +244,9 @@ def _cmd_solve(args) -> int:
 
 
 def main(argv=None) -> int:
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m repro.launch.inspect", description=__doc__
     )
@@ -264,7 +268,8 @@ def main(argv=None) -> int:
         "solve", help="demo solve with convergence telemetry + sparkline"
     )
     p.add_argument("--smoke", action="store_true")
-    p.add_argument("--n", type=int, default=1024)
+    p.add_argument("--n-side", type=int, default=10,
+                   help="grid side (3-D Poisson: n_side^3 rows)")
     p.add_argument("--solver", default="cg",
                    choices=("cg", "fcg", "bicgstab", "cgs", "gmres"))
     p.add_argument("--executor", default="xla")

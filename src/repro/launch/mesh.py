@@ -15,53 +15,14 @@ __all__ = [
     "make_production_mesh",
     "make_host_mesh",
     "make_shard_mesh",
-    "compat_make_mesh",
-    "use_mesh",
-    "shard_map",
 ]
 
 
-def use_mesh(mesh: jax.sharding.Mesh):
-    """Ambient-mesh context: ``jax.set_mesh`` on new jax; on older versions
-    the ``Mesh`` object itself is the context manager that sets the ambient
-    physical mesh."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
-
-
-def shard_map(f, mesh=None, **kw):
-    """``jax.shard_map`` where available; the experimental version plus the
-    ambient mesh on older jax (which requires an explicit mesh argument and
-    spells ``check_vma`` as ``check_rep``)."""
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        return native(f, mesh=mesh, **kw) if mesh is not None else native(f, **kw)
-    from jax.experimental.shard_map import shard_map as legacy
-
-    if "check_vma" in kw:
-        kw["check_rep"] = kw.pop("check_vma")
-    # legacy shard_map's transpose mishandles symbolic-Zero cotangents (grads
-    # of partially-used outputs) unless replication checking is off
-    kw.setdefault("check_rep", False)
-    if mesh is None:
-        from jax._src.mesh import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-    return legacy(f, mesh=mesh, **kw)
-
-
-def compat_make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API supports them.
-
-    ``jax.sharding.AxisType`` only exists on newer jax; older versions default
-    to the same auto-sharded behaviour, so omit the argument there.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
+    # Auto axes: the sharding annotations on the arrays drive placement
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -71,7 +32,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     all-reduce crosses pods once per step over DCN)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_shard_mesh(num_shards: int, axis: str = "data") -> jax.sharding.Mesh:
@@ -96,4 +57,4 @@ def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     n = len(jax.devices())
     if data * model > n:
         raise ValueError(f"mesh {data}x{model} needs {data*model} devices, have {n}")
-    return compat_make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

@@ -22,6 +22,7 @@ import numpy as np
 from repro.configs import get_config, get_smoke_config
 from repro.observability import trace
 from repro.launch import steps as steps_lib
+from repro.launch.cache import use_compile_cache
 from repro.models import lm
 
 
@@ -88,6 +89,7 @@ def serve(cfg, *, batch: int, prompt_len: int, gen_len: int, seed: int = 0,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

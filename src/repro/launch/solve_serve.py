@@ -25,6 +25,7 @@ import argparse
 import time
 
 from repro.core import make_executor, use_executor
+from repro.launch.cache import use_compile_cache
 from repro.observability import metrics, trace
 from repro.serve import ServeConfig, SolveService, TrafficConfig
 from repro.serve.traffic import generate_traffic, pattern_gallery
@@ -116,6 +117,7 @@ def _fmt_s(v) -> str:
 
 
 def main(argv=None) -> int:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small end-to-end run for CI (48 requests)")

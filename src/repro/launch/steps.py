@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed import sharding as shd
-from repro.launch.mesh import shard_map
 from repro.models import lm
 from repro.optim.adamw import AdamWState
 
@@ -109,7 +108,7 @@ def make_compressed_dp_train_step(cfg, optimizer, data_axis: str = "data"):
         batch_specs = {k: P(data_axis) for k in batch}
         stats_specs = {k: P() for k in
                        ("loss", "lr", "grad_norm", "param_norm")}
-        return shard_map(
+        return jax.shard_map(
             body,
             in_specs=(replicated, opt_rep, err_specs, batch_specs),
             out_specs=(replicated, opt_rep, err_specs, stats_specs),

@@ -33,6 +33,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.data import DataConfig, DataIterator, entropy_floor
 from repro.distributed import sharding as shd
 from repro.launch import steps as steps_lib
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import lm
 from repro.optim import adamw, warmup_cosine_schedule
@@ -179,6 +180,7 @@ def train_deq(*, steps: int, batch: int, lr: float = 3e-2,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--model", default="lm", choices=["lm", "deq"])

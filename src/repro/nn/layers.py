@@ -16,9 +16,6 @@ import jax.numpy as jnp
 from repro.core import registry
 from repro.nn.common import ParamBuilder, ones_init, zeros_init
 
-# make sure the kernel spaces are populated
-import repro.kernels  # noqa: F401
-
 _rmsnorm_op = registry.operation("nn_rmsnorm")
 
 

@@ -27,8 +27,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import axis_size
-from repro.launch.mesh import shard_map
 from repro.nn.common import ParamBuilder
 
 
@@ -138,7 +136,7 @@ def _experts_ep_body(x2, router_w, gate_l, up_l, down_l, cfg, model_axis):
     T, d = x2.shape
     E_pad_local = gate_l.shape[0]
     m = jax.lax.axis_index(model_axis)
-    n_cols = axis_size(model_axis)
+    n_cols = jax.lax.axis_size(model_axis)
     k = cfg.top_k
 
     # router (replicated weights; computed redundantly per column — cheap)
@@ -206,7 +204,7 @@ def _experts_ep_a2a_body(x2, router_w, gate_l, up_l, down_l, cfg, model_axis):
     """
     T, d = x2.shape
     E_local = gate_l.shape[0]
-    n_cols = axis_size(model_axis)
+    n_cols = jax.lax.axis_size(model_axis)
     k = cfg.top_k
 
     logits = x2.astype(jnp.float32) @ router_w
@@ -337,7 +335,7 @@ def _experts_ep(p, x, cfg):
 
     out_specs = (x_spec, {
         "moe_lb_loss": P(), "moe_z_loss": P(), "moe_drop_frac": P()})
-    return shard_map(
+    return jax.shard_map(
         body, in_specs=tuple(in_specs), out_specs=out_specs
     )(*args)
 

@@ -73,13 +73,10 @@ def unit_roundoff(dtype) -> float:
     """
     return float(jnp.finfo(jnp.dtype(dtype)).eps) / 2.0
 
+# the kernel spaces (reference/xla/pallas) bind in repro.kernels.block_jacobi
 block_jacobi_apply_op = registry.operation(
     "block_jacobi_apply", "batched small-matvec y[b] = inv_blocks[b] @ v[b]"
 )
-
-# bind the kernel spaces (reference/xla/pallas) for the apply — the analogue
-# of linking the device backends; without this the op has no implementations
-import repro.kernels.block_jacobi.ops  # noqa: E402,F401
 
 
 # =============================================================================
@@ -155,20 +152,18 @@ def _extract_blocks_host(A, block_ptrs: np.ndarray) -> Tuple[np.ndarray, np.ndar
     bs = int(sizes.max()) if nb else 1
     dtype = values.dtype if values.size else np.float32
     blocks = np.zeros((nb, bs, bs), dtype)
-    for b in range(nb):
-        lo, hi = int(block_ptrs[b]), int(block_ptrs[b + 1])
-        for i in range(lo, hi):
-            cols = indices[indptr[i] : indptr[i + 1]]
-            vals = values[indptr[i] : indptr[i + 1]]
-            keep = (cols >= lo) & (cols < hi)
-            blocks[b, i - lo, cols[keep] - lo] = vals[keep]
-        # identity padding beyond the block's true size
-        for l in range(hi - lo, bs):
-            blocks[b, l, l] = 1.0
-        # empty-row fallback: a structurally zero row cannot be inverted
-        for l in range(hi - lo):
-            if not blocks[b, l].any():
-                blocks[b, l, l] = 1.0
+    # each stored entry lands in its row's block when its column is inside
+    rows = np.repeat(np.arange(len(indptr) - 1, dtype=np.int64), np.diff(indptr))
+    blk = np.searchsorted(block_ptrs, rows, side="right") - 1
+    lo = block_ptrs[blk]
+    keep = (indices >= lo) & (indices < block_ptrs[blk + 1])
+    blocks[blk[keep], (rows - lo)[keep], (indices - lo)[keep]] = values[keep]
+    local = np.arange(bs)[None, :]
+    # identity padding beyond the block's true size, and the empty-row
+    # fallback: a structurally zero row cannot be inverted
+    unit = (local >= sizes[:, None]) | ~blocks.any(axis=2)
+    b_idx, l_idx = np.nonzero(unit)
+    blocks[b_idx, l_idx, l_idx] = 1.0
     return blocks, sizes
 
 
@@ -355,6 +350,14 @@ class BlockJacobi(LinOp):
         )
 
 
+# a pytree, so a solve can be jitted with the preconditioner as an argument
+jax.tree_util.register_dataclass(
+    BlockJacobi,
+    data_fields=["inv_blocks", "gather_idx", "scatter_idx"],
+    meta_fields=["n", "block_size", "num_blocks", "executor"],
+)
+
+
 def block_jacobi(
     A,
     block_size: Optional[int] = None,
@@ -397,13 +400,18 @@ def block_jacobi(
     class_id = _class_ids(adaptive, blocks_np, inv_np, sizes, tau, base_dtype)
     order = np.argsort(class_id, kind="stable")
 
-    # gather/scatter maps in class order (host-precomputed, device gathers)
+    # gather/scatter maps in class order (host-precomputed, device gathers):
+    # row r sits at local slot r - lo of its block, which sits at position
+    # pos_of[block] of the class order
     gather = np.full((nb, bs), n, np.int32)
     scatter = np.zeros(n, np.int32)
-    for pos, b in enumerate(order):
-        lo, size = int(block_ptrs[b]), int(sizes[b])
-        gather[pos, :size] = np.arange(lo, lo + size, dtype=np.int32)
-        scatter[lo : lo + size] = pos * bs + np.arange(size, dtype=np.int32)
+    pos_of = np.empty(nb, np.int64)
+    pos_of[order] = np.arange(nb)
+    r = np.arange(n, dtype=np.int64)
+    blk = np.repeat(np.arange(nb, dtype=np.int64), sizes)
+    slot, pos = r - block_ptrs[blk], pos_of[blk]
+    gather[pos, slot] = r
+    scatter[r] = pos * bs + slot
 
     classes = _storage_classes(base_dtype)
     tensors = []

@@ -190,6 +190,12 @@ class ScalarJacobi(LinOp):
         return self
 
 
+# a pytree, so a solve can be jitted with the preconditioner as an argument
+jax.tree_util.register_pytree_node(
+    ScalarJacobi, lambda s: ((s.inv_diag,), None), lambda _, c: ScalarJacobi(*c)
+)
+
+
 def probe_symmetry(A, *, seed: int = 0, rtol: float = 1e-4) -> Optional[bool]:
     """Cheap seeded two-vector symmetry probe: is ``u^T A v == v^T A u``?
 

@@ -16,7 +16,7 @@ inner-outer Krylov, Ginkgo's solver-as-preconditioner pattern.
 
 Precision note: the paper evaluates in IEEE754 double precision; on this CPU
 container f64 requires ``jax_enable_x64``.  Solvers are dtype-polymorphic —
-benchmarks run f32 by default and f64 under ``with jax.experimental.enable_x64()``.
+benchmarks run f32 by default and f64 under ``with jax.enable_x64(True)``.
 """
 
 from __future__ import annotations

@@ -4,7 +4,7 @@ Reference space = sequential-semantics oracle (straightforward scatter/gather).
 XLA space       = segment-sum / one-shot vectorized formulations the compiler
                   can fuse (Ginkgo's "OpenMP" slot).
 Pallas space    = registered from ``repro.kernels.spmv_sellp`` / ``..._ell``
-                  (hardware-native; imported lazily by ``repro.kernels``).
+                  (hardware-native; bound when ``repro`` is imported).
 
 ``apply(A, x)`` mirrors ``gko::LinOp::apply`` — dispatch on format type, then on
 executor kernel space.

@@ -2,7 +2,6 @@
 
 import numpy as np
 import jax
-from jax import experimental as jax_experimental
 import jax.numpy as jnp
 import pytest
 from _hyp_compat import given, st
@@ -95,7 +94,7 @@ def test_ballot_wavefront64_needs_x64():
 
 
 def test_ballot_wavefront64_under_x64():
-    with jax_experimental.enable_x64(True):
+    with jax.enable_x64(True):
         pred = jnp.asarray(np.tile(np.arange(64) % 3 == 0, 2))
         sg = coop.subgroup(jnp.zeros((128,)), 8, warp_size=64)
         cnt = np.asarray(sg.count(pred)).reshape(16, 8)[:, 0]

@@ -175,3 +175,51 @@ def test_dispatch_log_counts_model_ops(rng):
     op(x, w, executor=ex)
     op(x, w, executor=ex)
     assert ex.dispatch_log["nn_rmsnorm"] == 2
+
+
+# -- device selection by device_kind -------------------------------------------
+
+
+class _StubDevice:
+    """Stands in for a ``jax.Device``: only the fields selection reads."""
+
+    def __init__(self, platform, device_kind):
+        self.platform = platform
+        self.device_kind = device_kind
+
+
+def test_tpu_v5e_kind_selects_tpu_v5e():
+    from repro.core import executor_for_device, params_for_device
+
+    dev = _StubDevice("tpu", "TPU v5 lite")
+    assert params_for_device(dev).name == "tpu_v5e"
+    ex = executor_for_device(dev)
+    assert isinstance(ex, PallasTpuExecutor) and not ex.interpret
+    assert ex.hw.name == "tpu_v5e"
+
+
+def test_unknown_tpu_kind_raises():
+    from repro.core import executor_for_device
+
+    with pytest.raises(KeyError, match="TPU v99"):
+        executor_for_device(_StubDevice("tpu", "TPU v99"))
+
+
+def test_cpu_kind_selects_cpu_xla():
+    import jax
+
+    from repro.core import executor_for_device
+
+    ex = executor_for_device(_StubDevice("cpu", "cpu"))
+    assert isinstance(ex, XlaExecutor) and ex.hw.name == "cpu_xla"
+    # the real CPU device of this process maps the same way
+    assert executor_for_device(jax.devices()[0]).hw.name == "cpu_xla"
+
+
+def test_v5e_peaks_are_the_published_ones():
+    from repro.core import params as hw_params
+
+    v5e = hw_params.TPU_V5E
+    assert v5e.peak_flops_bf16 == 197e12
+    assert v5e.hbm_bandwidth == 819e9
+    assert v5e.ici_bandwidth == 1600e9 / 8  # 1,600 Gbit/s

@@ -152,7 +152,7 @@ def test_psum_norm_padding_regression(require_devices):
 @pytest.mark.parametrize("parts", SHARDS)
 def test_cg_parity_f64(parts, fmt, require_devices):
     require_devices(parts)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         a, _, b = spd_system(dtype=np.float64)
         A = BUILD[fmt](a)
         stop = Stop(max_iters=500, reduction_factor=1e-12)

@@ -29,7 +29,7 @@ def test_pjit_train_step_executes():
         import numpy as np, jax, jax.numpy as jnp
         from repro.configs import get_smoke_config
         from repro.launch import steps as steps_lib
-        from repro.launch.mesh import make_host_mesh, use_mesh
+        from repro.launch.mesh import make_host_mesh
         from repro.distributed import sharding as shd
         from repro.models import lm
         from repro.optim import adamw, constant_schedule
@@ -51,7 +51,7 @@ def test_pjit_train_step_executes():
         fn = jax.jit(steps_lib.make_train_step(cfg, opt),
                      in_shardings=(p_sh, opt_sh, b_sh),
                      out_shardings=(p_sh, opt_sh, None))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             p2, s2, m = fn(params, state, batch)
         loss = float(m["loss"])
         assert np.isfinite(loss), loss
@@ -65,20 +65,20 @@ def test_ring_collective_matmuls():
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collective_matmul import (
             ring_reduce_scatter_matmul, ring_all_gather_matmul)
-        from repro.launch.mesh import make_host_mesh, use_mesh, shard_map
+        from repro.launch.mesh import make_host_mesh
 
         mesh = make_host_mesh(1, 8)
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.normal(size=(16, 64)).astype(np.float32))
         w = jnp.asarray(rng.normal(size=(64, 32)).astype(np.float32))
         want = np.asarray(x @ w)
-        with use_mesh(mesh):
-            got = jax.jit(shard_map(
+        with jax.set_mesh(mesh):
+            got = jax.jit(jax.shard_map(
                 lambda xs, ws: ring_reduce_scatter_matmul(xs, ws, "model"),
                 in_specs=(P(None, "model"), P("model", None)),
                 out_specs=P(None, "model")))(x, w)
             assert np.abs(np.asarray(got) - want).max() < 1e-3
-            got2 = jax.jit(shard_map(
+            got2 = jax.jit(jax.shard_map(
                 lambda xs, ws: ring_all_gather_matmul(xs, ws, "model"),
                 in_specs=(P("model", None), P(None, "model")),
                 out_specs=P(None, "model")))(x, w)
@@ -92,7 +92,7 @@ def test_moe_expert_parallel_matches_dense():
         import numpy as np, jax, jax.numpy as jnp
         from repro.configs import ModelConfig
         from repro.nn import moe as moe_lib
-        from repro.launch.mesh import make_host_mesh, use_mesh
+        from repro.launch.mesh import make_host_mesh
 
         mesh = make_host_mesh(2, 4)
         cfg = ModelConfig(name='t', family='moe', n_layers=1, d_model=32,
@@ -102,7 +102,7 @@ def test_moe_expert_parallel_matches_dense():
         p, _ = moe_lib.moe_init(jax.random.PRNGKey(0), cfg)
         rng = np.random.default_rng(50)
         x = jnp.asarray(rng.normal(size=(4, 16, 32)).astype(np.float32))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             y_ep, m = jax.jit(lambda x: moe_lib.moe_forward(p, x, cfg, impl="ep"))(x)
         y_dense, _ = moe_lib.moe_forward(p, x, cfg, impl="dense")
         assert np.abs(np.asarray(y_ep) - np.asarray(y_dense)).max() < 1e-4
@@ -114,7 +114,7 @@ def test_moe_expert_parallel_matches_dense():
         def loss_dense(p, x):
             y, _ = moe_lib.moe_forward(p, x, cfg, impl="dense")
             return jnp.sum(y**2)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             g_ep = jax.jit(jax.grad(loss_ep))(p, x)
         g_dense = jax.grad(loss_dense)(p, x)
         for key in ("gate", "up", "down", "router"):
@@ -130,7 +130,7 @@ def test_compressed_psum_shard_map():
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.optim import compressed_psum, init_error_state
-        from repro.launch.mesh import make_host_mesh, use_mesh, shard_map
+        from repro.launch.mesh import make_host_mesh
 
         mesh = make_host_mesh(8, 1)
         rng = np.random.default_rng(1)
@@ -141,8 +141,8 @@ def test_compressed_psum_shard_map():
             out, new_e = compressed_psum({"w": g_l[0]}, {"w": e_l[0]}, "data")
             return out["w"][None], new_e["w"][None]
 
-        with use_mesh(mesh):
-            out, new_err = jax.jit(shard_map(
+        with jax.set_mesh(mesh):
+            out, new_err = jax.jit(jax.shard_map(
                 body, in_specs=(P("data", None), P("data", None)),
                 out_specs=(P("data", None), P("data", None))))(g, err)
         want = np.asarray(g).mean(axis=0)
@@ -187,7 +187,7 @@ def test_sequence_parallel_constraint_executes():
         import dataclasses
         import numpy as np, jax, jax.numpy as jnp
         from repro.configs import get_smoke_config
-        from repro.launch.mesh import make_host_mesh, use_mesh
+        from repro.launch.mesh import make_host_mesh
         from repro.models import lm
 
         mesh = make_host_mesh(2, 4)
@@ -197,7 +197,7 @@ def test_sequence_parallel_constraint_executes():
         params, _ = lm.init_model(jax.random.PRNGKey(0), cfg)
         rng = np.random.default_rng(0)
         tokens = jnp.asarray(rng.integers(0, cfg.vocab, (4, 16)), jnp.int32)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             logits_sp, _ = jax.jit(lambda t: lm.forward(params, cfg, tokens=t))(tokens)
         cfg0 = dataclasses.replace(cfg, sp_spec=(), attn_impl="dense")
         logits, _ = lm.forward(params, cfg0, tokens=tokens)
@@ -215,7 +215,7 @@ def test_compressed_dp_training_converges():
         from repro.configs import get_smoke_config
         from repro.data import DataConfig, global_step_batch
         from repro.launch import steps as steps_lib
-        from repro.launch.mesh import make_host_mesh, use_mesh
+        from repro.launch.mesh import make_host_mesh
         from repro.models import lm
         from repro.optim import adamw, constant_schedule
 
@@ -239,7 +239,7 @@ def test_compressed_dp_training_converges():
         p_c, s_c = params, opt.init(params)
         err = init_err(params, 8)
         c_losses = []
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             fn = jax.jit(step_c)
             for i in range(12):
                 batch = {k: jnp.asarray(v) for k, v in global_step_batch(dcfg, i).items()}
@@ -267,7 +267,9 @@ def test_dryrun_cell_end_to_end():
         def small(*, multi_pod=False):
             shape = (2, 4, 8) if multi_pod else (8, 8)
             axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-            return mesh_lib.compat_make_mesh(shape, axes)
+            return jax.make_mesh(
+                shape, axes,
+                axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
         mesh_lib.make_production_mesh = small
         dr.make_production_mesh = small
 

@@ -19,15 +19,16 @@ from repro.kernels.spmv_dot.ref import spmv_dot_ell_ref
 
 # -- spmv_dot_ell ----------------------------------------------------------------
 
-@pytest.mark.parametrize("bm,bk,coop", [(64, 8, True), (128, 16, False), (37, 5, True)])
-def test_spmv_dot_ell_blocks(rng, bm, bk, coop):
-    a = rng.normal(size=(150, 150)).astype(np.float32)
+@pytest.mark.parametrize("bm,bk", [(1024, 8), (2048, 16), (37, 5)])
+def test_spmv_dot_ell_blocks(rng, bm, bk):
+    # 2500 rows: several row blocks of 1024 plus a padded tail
+    a = rng.normal(size=(2500, 150)).astype(np.float32)
     a[rng.random(a.shape) < 0.85] = 0
     A = sparse.ell_from_dense(a)
     x = jnp.asarray(rng.normal(size=(150,)).astype(np.float32))
-    w = jnp.asarray(rng.normal(size=(150,)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(2500,)).astype(np.float32))
     y, d = spmv_dot_ell(A.col_idx, A.values, x, w, block_m=bm, block_k=bk,
-                        use_coop=coop, interpret=True)
+                        interpret=True)
     y_ref, d_ref = spmv_dot_ell_ref(A.col_idx, A.values, x, w)
     np.testing.assert_allclose(y, y_ref, rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(float(d), float(d_ref), rtol=1e-4, atol=1e-4)
@@ -56,9 +57,9 @@ def test_spmv_dot_ell_sweep(m, seed):
 
 # -- axpy_norm -------------------------------------------------------------------
 
-@pytest.mark.parametrize("block_n", [128, 1024, 100])
+@pytest.mark.parametrize("block_n", [1024, 2048, 100])
 def test_axpy_norm_blocks(rng, block_n):
-    n = 777
+    n = 2777  # several blocks of 1024 plus a padded tail
     x = jnp.asarray(rng.normal(size=(n,)).astype(np.float32))
     y = jnp.asarray(rng.normal(size=(n,)).astype(np.float32))
     z, ss = axpy_norm(-0.37, x, y, block_n=block_n, interpret=True)

@@ -154,8 +154,9 @@ def test_spmv_batch_ell_vmem_fallback(rng):
 
 
 def test_spmv_vmem_fallback_serves_pallas_space(rng):
-    """A target whose VMEM cannot hold x still answers (via the xla kernel
-    inside the pallas binding) and matches the oracle."""
+    """A starved target still answers through the pallas kernel at its
+    smallest tile (x is never VMEM-resident, so no size switches kernels)
+    and matches the oracle."""
     import dataclasses
 
     from repro.core import params as hw_params

@@ -1,0 +1,137 @@
+"""Compile the solve path for a described TPU v5e at 2,097,152 rows.
+
+Nothing runs: each test lowers through the library's normal dispatch (a
+:class:`PallasTpuExecutor`, the registry, the tuning tables) and compiles for
+one chip of a ``v5e:2x2`` topology that is described, not attached — what the
+chip's compiler would refuse (block shapes off the (8, 128) tiling, scalar
+stores to VMEM, more VMEM than the kernel asked for) fails here.  Sizes are
+the 128³ Poisson system the chip smoke test solves: 7 ELL slots per row,
+8x8 Jacobi blocks.
+
+The topology is described inside a fixture, never at import, so every test
+worker collects the same tests and only the worker that runs this file loads
+the TPU compiler.
+"""
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import PallasTpuExecutor, params as hw_params, registry
+
+M = 128 ** 3  # rows of poisson_3d(128)
+K = 7  # 7-point stencil
+BS = 8  # block-Jacobi block size
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a described-device compile is written to the persistent cache but can
+    # never be read back without a chip; keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def ex():
+    return PallasTpuExecutor(hw_params.TPU_V5E)
+
+
+def _sds(one_chip, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _ell(one_chip):
+    from repro.sparse import Ell
+
+    return Ell(
+        col_idx=_sds(one_chip, (M, K), jnp.int32),
+        values=_sds(one_chip, (M, K)),
+        shape=(M, M),
+    )
+
+
+def _compile(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the compiled program"
+    return text
+
+
+def test_spmv_ell_compiles(one_chip, ex):
+    op = registry.operation("spmv_ell")
+    assert op.space_used(ex) == "pallas"
+    _compile(lambda A, x: op(A, x, executor=ex), _ell(one_chip), _sds(one_chip, (M,)))
+
+
+def test_spmv_dot_ell_compiles(one_chip, ex):
+    op = registry.operation("spmv_dot_ell")
+    assert op.space_used(ex) == "pallas"
+    vec = _sds(one_chip, (M,))
+    _compile(lambda A, x, w: op(A, x, w, executor=ex), _ell(one_chip), vec, vec)
+
+
+def test_axpy_norm_compiles(one_chip, ex):
+    op = registry.operation("axpy_norm")
+    assert op.space_used(ex) == "pallas"
+    vec = _sds(one_chip, (M,))
+    _compile(
+        lambda a, x, y: op(a, x, y, executor=ex), _sds(one_chip, ()), vec, vec
+    )
+
+
+@pytest.mark.parametrize("storage", ["float32", "bfloat16"])
+def test_block_jacobi_apply_compiles(one_chip, ex, storage):
+    op = registry.operation("block_jacobi_apply")
+    assert op.space_used(ex) == "pallas"
+    nb = M // BS
+    _compile(
+        lambda inv, vp: op(inv, vp, executor=ex),
+        _sds(one_chip, (nb, BS, BS), jnp.dtype(storage)),
+        _sds(one_chip, (nb, BS)),
+    )
+
+
+def test_fused_cg_block_jacobi_compiles(one_chip, ex):
+    """The whole fused-CG ``while_loop`` with block-Jacobi, as one program."""
+    from repro.precond import BlockJacobi
+    from repro.solvers import krylov
+    from repro.solvers.common import Stop
+
+    nb = M // BS
+
+    def solve(A, inv, gather_idx, scatter_idx, b):
+        M_ = BlockJacobi(
+            inv_blocks=(inv,), gather_idx=gather_idx, scatter_idx=scatter_idx,
+            n=M, block_size=BS, num_blocks=nb,
+        )
+        res = krylov.cg(A, b, M=M_, executor=ex, strict=False,
+                        stop=Stop(max_iters=1000, reduction_factor=1e-6))
+        return res.x, res.iterations
+
+    text = _compile(
+        solve,
+        _ell(one_chip),
+        _sds(one_chip, (nb, BS, BS)),
+        _sds(one_chip, (nb, BS), jnp.int32),
+        _sds(one_chip, (M,), jnp.int32),
+        _sds(one_chip, (M,)),
+    )
+    assert "while" in text
+    # the fused loop: spmv_dot_ell, axpy_norm and block_jacobi_apply kernels
+    assert text.count("tpu_custom_call") >= 3

@@ -39,7 +39,7 @@ def test_xla_cost_analysis_undercounts_loops():
         return c
 
     compiled = jax.jit(scanned).lower(x, w).compile()
-    hlo_flops = costmodel.hlo_cost_analysis(compiled).get("flops", 0.0)
+    hlo_flops = compiled.cost_analysis().get("flops", 0.0)
     one_body = 2 * 8 * d * d
     assert hlo_flops < 2 * one_body  # ~1x body, not 10x
 

@@ -347,16 +347,18 @@ def test_block_jacobi_uses_launch_config():
     base = ex.launch_config("block_jacobi", shapes)
     assert set(base.block) == {"block_nb"}
     try:
-        tuning.set_table_entry("block_jacobi", ex.hw.name, {"block_nb": 16})
+        # a whole number of (8 sublane x 128 lane) tiles of blocks
+        tuning.set_table_entry("block_jacobi", ex.hw.name, {"block_nb": 2048})
         pinned = ex.launch_config("block_jacobi", shapes)
-        assert pinned["block_nb"] == 16
+        assert pinned["block_nb"] == 2048
     finally:
         tuning._TABLE.pop(("block_jacobi", ex.hw.name), None)
 
 
 def test_block_jacobi_vmem_fallback():
-    """A starved target still serves the apply (portable formulation inside
-    the pallas binding) and matches the oracle."""
+    """A starved target still serves the apply through the pallas kernel at
+    its smallest tile (no switch to another formulation) and matches the
+    oracle."""
     rng = np.random.default_rng(6)
     inv = jnp.asarray(rng.normal(size=(16, 8, 8)).astype(np.float32))
     vp = jnp.asarray(rng.normal(size=(16, 8)).astype(np.float32))
@@ -365,3 +367,52 @@ def test_block_jacobi_vmem_fallback():
     got = op(inv, vp, executor=PallasInterpretExecutor(starved))
     want = op(inv, vp, executor=ReferenceExecutor())
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+def _extract_blocks_loop(indptr, indices, values, block_ptrs):
+    """Row-by-row reference for the vectorized host block extraction."""
+    sizes = np.diff(block_ptrs)
+    nb, bs = len(sizes), int(sizes.max())
+    blocks = np.zeros((nb, bs, bs), values.dtype)
+    for b in range(nb):
+        lo, hi = int(block_ptrs[b]), int(block_ptrs[b + 1])
+        for i in range(lo, hi):
+            cols = indices[indptr[i] : indptr[i + 1]]
+            vals = values[indptr[i] : indptr[i + 1]]
+            keep = (cols >= lo) & (cols < hi)
+            blocks[b, i - lo, cols[keep] - lo] = vals[keep]
+        for l in range(hi - lo, bs):
+            blocks[b, l, l] = 1.0
+        for l in range(hi - lo):
+            if not blocks[b, l].any():
+                blocks[b, l, l] = 1.0
+    return blocks
+
+
+def test_host_block_extraction_matches_row_loop():
+    """Ragged block pointers, empty rows, off-block entries: the vectorized
+    extraction and the class-ordered gather/scatter maps equal the
+    row-by-row construction exactly."""
+    from repro.precond.block_jacobi import _extract_blocks_host
+
+    rng = np.random.default_rng(11)
+    n = 53
+    a = rng.normal(size=(n, n)).astype(np.float32)
+    a[rng.random(a.shape) < 0.8] = 0.0
+    a[[4, 17, 30]] = 0.0  # structurally empty rows
+    A = sparse.csr_from_dense(a)
+    ptrs = np.array([0, 3, 8, 9, 16, 24, 31, 40, 47, 53])
+    blocks, sizes = _extract_blocks_host(A, ptrs)
+    indptr, indices, values = sparse.csr_host_arrays(A)
+    np.testing.assert_array_equal(
+        blocks, _extract_blocks_loop(indptr, indices, values, ptrs)
+    )
+    np.testing.assert_array_equal(sizes, np.diff(ptrs))
+
+    M = block_jacobi(A, blocks=ptrs, adaptive=True, executor=XlaExecutor())
+    gather, scatter = np.asarray(M.gather_idx), np.asarray(M.scatter_idx)
+    bs = M.block_size
+    for r in range(n):  # row r sits in slot scatter[r] and is gathered back
+        assert gather.reshape(-1)[scatter[r]] == r
+    assert (gather == n).sum() == gather.size - n  # the rest are pad slots
+    assert scatter.max() < M.num_blocks * bs

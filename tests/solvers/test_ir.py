@@ -9,7 +9,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from jax import experimental as jax_experimental
 
 from repro import solvers, sparse
 from repro.core import (
@@ -52,7 +51,7 @@ F64_STOP = solvers.Stop(max_iters=100, reduction_factor=1e-12)
 def test_mixed_precision_ir_reaches_f64_tolerance(fixture):
     """f32 inner CG + x64 outer residual converges to the f64 tolerance —
     far below anything a pure-f32 solve can reach."""
-    with jax_experimental.enable_x64(True):
+    with jax.enable_x64(True):
         a = fixture()
         n = a.shape[0]
         A = sparse.csr_from_dense(a)
@@ -78,7 +77,7 @@ def test_mixed_precision_ir_outer_sweeps_are_few():
     """IR theory: each outer sweep contracts the error by ~ the inner solve
     accuracy; reaching 1e-12 from an sqrt(u_f32) ~ 2e-4 inner tolerance
     should take a handful of sweeps, not tens."""
-    with jax_experimental.enable_x64(True):
+    with jax.enable_x64(True):
         a = spd_dense()
         A = sparse.csr_from_dense(a)
         b = jnp.asarray(a @ np.ones(a.shape[0]))
@@ -92,7 +91,7 @@ def test_mixed_precision_ir_outer_sweeps_are_few():
     "exec_cls", [ReferenceExecutor, XlaExecutor, PallasInterpretExecutor]
 )
 def test_mixed_precision_ir_cross_executor(exec_cls):
-    with jax_experimental.enable_x64(True):
+    with jax.enable_x64(True):
         a = spd_dense(48)
         A = sparse.csr_from_dense(a)
         xstar = np.random.default_rng(1).normal(size=48)
@@ -169,7 +168,7 @@ def test_ir_solver_factory_is_linop():
 
 
 def test_mixed_precision_ir_is_jittable():
-    with jax_experimental.enable_x64(True):
+    with jax.enable_x64(True):
         a = spd_dense(48)
         A = sparse.csr_from_dense(a)
         xstar = np.random.default_rng(6).normal(size=48)
@@ -186,7 +185,7 @@ def test_unit_roundoff_table():
     assert unit_roundoff(jnp.float16) == 2.0**-11
     assert unit_roundoff(jnp.bfloat16) == 2.0**-8
     assert unit_roundoff(jnp.float32) == 2.0**-24
-    with jax_experimental.enable_x64(True):
+    with jax.enable_x64(True):
         assert unit_roundoff(jnp.float64) == 2.0**-53
 
 
