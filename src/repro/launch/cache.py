@@ -15,8 +15,8 @@ import jax
 __all__ = ["CHECKOUT_CACHE_DIR", "use_compile_cache"]
 
 #: ``<checkout>/.jax_cache`` — this file is ``<checkout>/src/repro/launch/cache.py``
-CHECKOUT_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..", ".jax_cache"
+CHECKOUT_CACHE_DIR = os.path.normpath(
+    os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "..", "..", ".jax_cache")
 )
 
 
@@ -25,6 +25,5 @@ def use_compile_cache() -> str:
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    path = os.path.normpath(CHECKOUT_CACHE_DIR)
-    jax.config.update("jax_compilation_cache_dir", path)
-    return path
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    return CHECKOUT_CACHE_DIR
