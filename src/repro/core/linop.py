@@ -98,16 +98,20 @@ class LinOp:
         The four-argument form is Ginkgo's advanced apply; it is what lets IR
         fuse the residual update ``r = b - A x`` into one operator call:
         ``A.apply(-1.0, x, 1.0, b)``.
+
+        Runs inside ``jax.named_scope("<ClassName>.apply")``, which names the
+        operator's own glue (gathers, scatters, casts) on the device trace.
         """
         ex = executor if executor is not None else self.executor
-        if len(args) == 1:
-            return self._apply(args[0], ex)
-        if len(args) == 4:
+        if len(args) not in (1, 4):
+            raise TypeError(
+                f"apply takes (b) or (alpha, b, beta, x); got {len(args)} arguments"
+            )
+        with jax.named_scope(f"{type(self).__name__}.apply"):
+            if len(args) == 1:
+                return self._apply(args[0], ex)
             alpha, b, beta, x = args
             return alpha * self._apply(b, ex) + beta * x
-        raise TypeError(
-            f"apply takes (b) or (alpha, b, beta, x); got {len(args)} arguments"
-        )
 
     def __call__(self, b: jax.Array) -> jax.Array:
         return self.apply(b)

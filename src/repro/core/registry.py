@@ -17,6 +17,12 @@ Ginkgo semantics preserved:
 * every implementation receives the executor as first argument so it can read
   the hardware parameter table (Ginkgo kernels receive
   ``std::shared_ptr<const Executor>``).
+
+Every dispatch runs inside ``jax.named_scope(<op name>)``, so each device op
+it emits carries the op's name in its HLO ``op_name`` metadata, which a
+profiler trace reports beside the op.  The scope changes metadata only, never
+the compiled program, and under ``jit`` it costs something only while
+tracing.
 """
 
 from __future__ import annotations
@@ -24,6 +30,8 @@ from __future__ import annotations
 import functools
 import time
 from typing import Any, Callable, Dict, Tuple
+
+import jax
 
 # stdlib-only modules, safe to import before JAX-heavy layers come up
 from repro.observability import events as _events
@@ -118,20 +126,22 @@ class Operation:
 
         ex = executor if executor is not None else current_executor()
         space, impl = self.resolve(ex)
-        if not _trace.TRACING:
-            # hot path: identical to the pre-observability dispatch — one
-            # module-attribute check, no clock read, no allocation.
-            out = impl(ex, *args, **kwargs)
-            ex._note_dispatch(self.name)
-            return out
-        return self._traced_call(ex, space, impl, args, kwargs)
+        with jax.named_scope(self.name):
+            if not _trace.TRACING:
+                # hot path: one module-attribute check, no clock read, no
+                # allocation beyond the scope's name-stack entry.
+                out = impl(ex, *args, **kwargs)
+                ex._note_dispatch(self.name)
+                return out
+            return self._traced_call(ex, space, impl, args, kwargs)
 
     def _traced_call(self, ex, space, impl, args, kwargs):
         """Instrumented dispatch: structured event + Chrome trace span.
 
         Wall time here is dispatch/trace-time cost (under ``jit`` each op
-        runs once while tracing) — the event's value is launch *structure*:
+        runs once while tracing), so the event records launch *structure*:
         op, space, shapes, resolved LaunchConfig, bytes-moved estimate.
+        Device time is read from a profiler trace, by the op's scope.
         """
         tracer = _trace.get_tracer()
         ex._last_launch_config = None  # repopulated if the kernel resolves one
@@ -154,9 +164,6 @@ class Operation:
             tracer.complete(
                 self.name, ts_us, wall_us, cat="dispatch", args=event.to_args()
             )
-        from repro.observability import metrics as _metrics
-
-        _metrics.observe_dispatch(event, getattr(ex.hw, "hbm_bandwidth", None))
         return out
 
     def __repr__(self) -> str:

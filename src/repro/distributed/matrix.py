@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.linop import LinOp, MatrixFreeOp
+from repro.observability import trace
 from repro.distributed.partition import Partition
 from repro.sparse.formats import (
     Csr,
@@ -183,23 +184,34 @@ class DistLinOp(LinOp):
         raise NotImplementedError
 
     def local_operator(self, executor=None) -> LinOp:
+        """Per-shard operator; each part of its matvec runs in a named scope
+        (``<ClassName>.halo_exchange``, ``.interior``, ``.boundary``,
+        ``.halo``), which names its device ops on a profiler trace."""
         part = self.partition
         Lmax = part.max_part_size
         interior, boundary, halo, halo_map = self._local_blocks(executor)
+        cls = type(self).__name__
 
         def matvec(x_l):
             from repro.sparse import ops as sparse_ops
 
             if halo is None:
-                return sparse_ops.apply(interior, x_l, executor=executor)
+                with jax.named_scope(f"{cls}.interior"):
+                    return sparse_ops.apply(interior, x_l, executor=executor)
             # issue the collective FIRST, then the interior SpMV: interior
             # rows touch no halo column, so XLA's latency-hiding scheduler is
             # free to run that matvec while the all_gather is in flight; only
             # the boundary/halo contributions wait on the gathered x.
-            xg = jax.lax.all_gather(x_l, self.axis_name, tiled=True)
-            y = sparse_ops.apply(interior, x_l, executor=executor)
-            y = y + sparse_ops.apply(boundary, x_l, executor=executor)
-            return y + sparse_ops.apply(halo, xg[halo_map], executor=executor)
+            with jax.named_scope(f"{cls}.halo_exchange"):
+                xg = jax.lax.all_gather(x_l, self.axis_name, tiled=True)
+            with jax.named_scope(f"{cls}.interior"):
+                y = sparse_ops.apply(interior, x_l, executor=executor)
+            with jax.named_scope(f"{cls}.boundary"):
+                y = y + sparse_ops.apply(boundary, x_l, executor=executor)
+            with jax.named_scope(f"{cls}.halo_exchange"):
+                x_halo = xg[halo_map]
+            with jax.named_scope(f"{cls}.halo"):
+                return y + sparse_ops.apply(halo, x_halo, executor=executor)
 
         return MatrixFreeOp(matvec, shape=(Lmax, Lmax), dtype=self.dtype)
 
@@ -426,6 +438,11 @@ class DistEll(DistLinOp):
 
     @classmethod
     def from_matrix(cls, A, partition: Partition) -> "DistEll":
+        with trace.span("DistEll.from_matrix", cat="distributed"):
+            return cls._from_matrix(A, partition)
+
+    @classmethod
+    def _from_matrix(cls, A, partition: Partition) -> "DistEll":
         indptr, indices, values, n = _square_host_csr(A, partition)
         parts = split_by_rows(indptr, indices, values, partition)
         Lmax = partition.max_part_size

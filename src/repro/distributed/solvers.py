@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.distributed.matrix import DATA_AXIS, shard_specs
 from repro.distributed.precond import dist_preconditioner
+from repro.observability import trace
 from repro.solvers.common import SolveResult, Stop
 
 __all__ = ["dist_solve"]
@@ -60,15 +61,17 @@ def dist_solve(
     from repro.sparse import ops as sparse_ops
 
     part = A.partition
-    Md = dist_preconditioner(A, M, executor=executor, **(precond_opts or {}))
+    with trace.span("dist_solve.precond", cat="distributed"):
+        Md = dist_preconditioner(A, M, executor=executor, **(precond_opts or {}))
     # static branch: history changes the shard_map output arity, and the
     # option value is part of the _JIT_CACHE key, so each setting compiles
     # its own closure
     want_history = bool(options.get("history"))
 
-    bp = part.pad(b)
-    xp = part.pad(x0) if x0 is not None else jnp.zeros_like(bp)
-    mask = jnp.asarray(part.pad_mask)
+    with trace.span("dist_solve.pad", cat="distributed"):
+        bp = part.pad(b)
+        xp = part.pad(x0) if x0 is not None else jnp.zeros_like(bp)
+        mask = jnp.asarray(part.pad_mask)
 
     a_leaves, a_tree = jax.tree_util.tree_flatten(A)
     m_leaves, m_tree = jax.tree_util.tree_flatten(Md)
@@ -143,7 +146,8 @@ def dist_solve(
             )
         )
         _JIT_CACHE[key] = fn
-    outs = fn(a_leaves, m_leaves, bp, xp, mask)
+    with trace.span("dist_solve.run", cat="distributed"):
+        outs = fn(a_leaves, m_leaves, bp, xp, mask)
     xs, iters, rnorm, conv = outs[:4]
     hist = outs[4][0] if want_history else None
     return SolveResult(part.unpad(xs), iters[0], rnorm[0], conv[0], hist)

@@ -62,6 +62,7 @@ def axpy_norm(
     acc = jnp.promote_types(x.dtype, jnp.float32)
     z, ss = pl.pallas_call(
         _axpy_norm_kernel,
+        name="axpy_norm",
         grid=(rows // block_rows,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), vec, vec],
         out_specs=[vec, pl.BlockSpec((1, lanes.LANES), lambda i: (0, 0))],

@@ -60,6 +60,7 @@ def block_jacobi_apply(
     tile = (block_rows, lanes.LANES)
     out = pl.pallas_call(
         _block_jacobi_kernel,
+        name="block_jacobi_apply",
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec((bs, bs) + tile, lambda i: (0, 0, i, 0)),

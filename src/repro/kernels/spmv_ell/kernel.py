@@ -107,6 +107,7 @@ def ell_apply(
     itemsize = max(jnp.dtype(values.dtype).itemsize, jnp.dtype(dtype).itemsize)
     outs = pl.pallas_call(
         functools.partial(_ell_kernel, dot=dot),
+        name="spmv_dot_ell" if dot else "spmv_ell",
         grid=(rows // block_rows, pk // block_k),
         in_specs=in_specs,
         out_specs=out_specs,

@@ -17,7 +17,7 @@ imported lazily here.
 """
 
 from repro.observability import events, metrics, trace
-from repro.observability.events import DispatchEvent, DispatchLog, roofline_summary
+from repro.observability.events import DispatchEvent, DispatchLog
 from repro.observability.trace import span, validate_trace
 
 __all__ = [
@@ -27,7 +27,6 @@ __all__ = [
     "convergence",
     "DispatchEvent",
     "DispatchLog",
-    "roofline_summary",
     "span",
     "validate_trace",
 ]

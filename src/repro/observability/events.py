@@ -16,7 +16,9 @@ what Ginkgo's operation logger sees at a kernel launch:
   consulted one;
 * **wall time** of the dispatch (trace-time under ``jit`` — structure, not
   steady-state perf; see :mod:`repro.observability.trace`) and **estimated
-  bytes moved**, the roofline numerator.
+  bytes moved**.  No bandwidth is derived from the two: under ``jit`` the
+  wall time is tracing, not the device (device time comes from a profiler
+  trace, by the op's named scope).
 
 This module is stdlib-only on purpose: it is imported by
 ``repro.core.registry`` at module load, before JAX-heavy modules come up.
@@ -35,7 +37,6 @@ __all__ = [
     "summarize_operands",
     "shape_bucket",
     "make_event",
-    "roofline_summary",
 ]
 
 #: bounded so a long-running traced process cannot grow without limit; the
@@ -138,13 +139,6 @@ class DispatchEvent:
             args["launch"] = self.launch
         return args
 
-    @property
-    def gbs(self) -> float:
-        """Achieved GB/s of this dispatch (wall-time based; 0 when unknown)."""
-        if self.wall_us <= 0.0:
-            return 0.0
-        return self.est_bytes / (self.wall_us * 1e-6) / 1e9
-
 
 def make_event(
     *,
@@ -197,42 +191,3 @@ class DispatchLog(collections.Counter):
     def clear(self) -> None:  # tests clear counts + events as one unit
         super().clear()
         self.events.clear()
-
-
-def roofline_summary(
-    events,
-    hbm_bandwidth: Optional[float] = None,
-) -> List[Dict[str, Any]]:
-    """Aggregate dispatch events into per-(op, space, target) roofline rows.
-
-    Each row reports dispatch count, total estimated bytes, total wall time,
-    achieved GB/s, and — when ``hbm_bandwidth`` (bytes/s) is given — the
-    fraction of the bandwidth bound, i.e. the live analogue of the
-    ``frac_spmv_*`` pins in the BENCH snapshots.
-    """
-    agg: Dict[tuple, Dict[str, Any]] = {}
-    for ev in events:
-        key = (ev.op, ev.space, ev.target)
-        row = agg.get(key)
-        if row is None:
-            row = agg[key] = {
-                "op": ev.op,
-                "space": ev.space,
-                "target": ev.target,
-                "count": 0,
-                "est_bytes": 0,
-                "wall_us": 0.0,
-            }
-        row["count"] += 1
-        row["est_bytes"] += ev.est_bytes
-        row["wall_us"] += ev.wall_us
-    rows = []
-    for key in sorted(agg):
-        row = agg[key]
-        wall_s = row["wall_us"] * 1e-6
-        row["gbs"] = row["est_bytes"] / wall_s / 1e9 if wall_s > 0 else 0.0
-        if hbm_bandwidth:
-            row["bound_gbs"] = hbm_bandwidth / 1e9
-            row["frac_of_bound"] = row["gbs"] / (hbm_bandwidth / 1e9)
-        rows.append(row)
-    return rows

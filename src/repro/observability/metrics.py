@@ -284,20 +284,3 @@ def load_jsonl(path: str) -> List[Dict[str, Any]]:
             if line:
                 records.append(json.loads(line))
     return records
-
-
-def observe_dispatch(event, hbm_bandwidth: Optional[float] = None) -> None:
-    """Fold one :class:`~repro.observability.events.DispatchEvent` into the
-    default registry — dispatch counts, wall-time histograms, and (when the
-    event carries a bytes estimate) live achieved-GB/s gauges per
-    op x space x target, with frac-of-bound against ``hbm_bandwidth``."""
-    labels = {"op": event.op, "space": event.space, "target": event.target}
-    counter("dispatch_total", **labels).inc()
-    histogram("dispatch_wall_us", **labels).observe(event.wall_us)
-    if event.est_bytes and event.wall_us > 0:
-        g = event.gbs
-        gauge("dispatch_gbs", **labels).set(g)
-        if hbm_bandwidth:
-            gauge("dispatch_frac_of_bound", **labels).set(
-                g / (hbm_bandwidth / 1e9)
-            )

@@ -22,10 +22,18 @@ Activation:
 * programmatic: ``with trace.tracing("out.json"): ...`` or
   ``trace.enable(...)`` / ``trace.export()`` / ``trace.disable()``.
 
+While tracing is enabled, every span also enters
+``jax.profiler.TraceAnnotation(name)``: under a profiler session
+(``jax.profiler.trace``) it lands on the host plane of the same ``.xplane.pb``
+as the device ops, on the device trace's clock, so a device idle gap can be
+named by the host step that held it.  JAX is imported on the first enabled
+span, so this module stays importable without it.
+
 Timing caveat (documented, deliberate): under ``jit``, registered operations
 run once at *trace time* — dispatch events therefore measure dispatch/trace
 cost and launch *structure* (counts, shapes, geometry), while wall-clock truth
-lives in the driver-level spans that wrap ``block_until_ready``.
+lives in the driver-level spans that wrap ``block_until_ready`` and in the
+profiler's device trace.
 """
 
 from __future__ import annotations
@@ -90,10 +98,20 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """Open span: records one complete ("X") event on exit."""
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``, or ``None`` without JAX."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation(name)
 
-    __slots__ = ("tracer", "name", "cat", "args", "start_us")
+
+class _Span:
+    """Open span: a profiler annotation while open, and one complete ("X")
+    event on exit."""
+
+    __slots__ = ("tracer", "name", "cat", "args", "start_us", "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self.tracer = tracer
@@ -101,13 +119,19 @@ class _Span:
         self.cat = cat
         self.args = args
         self.start_us = 0.0
+        self.annotation = None
 
     def __enter__(self):
+        self.annotation = _annotation(self.name)
+        if self.annotation is not None:
+            self.annotation.__enter__()
         self.start_us = self.tracer.now_us()
         return self
 
     def __exit__(self, *exc):
         end = self.tracer.now_us()
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
         self.tracer.complete(
             self.name, self.start_us, end - self.start_us,
             cat=self.cat, args=self.args,

@@ -37,6 +37,7 @@ import numpy as np
 from repro.batch.linop import BatchLinOp
 from repro.core import registry
 from repro.core.linop import LinOp
+from repro.observability import trace
 from repro.sparse.formats import csr_host_arrays
 
 __all__ = [
@@ -375,6 +376,12 @@ def block_jacobi(
     ``adaptive=True`` turns on per-block storage-precision selection;
     a dtype forces every block into that storage.
     """
+    with trace.span("block_jacobi.generate", cat="precond"):
+        return _generate(A, block_size, blocks, adaptive, tau, executor)
+
+
+def _generate(A, block_size, blocks, adaptive, tau, executor) -> BlockJacobi:
+    """:func:`block_jacobi`'s steps, each in a ``block_jacobi.<step>`` span."""
     n = A.shape[0]
     if blocks is not None:
         block_ptrs = np.asarray(blocks, np.int64)
@@ -391,41 +398,47 @@ def block_jacobi(
             block_size = ex.hw.subgroup_size
         block_ptrs = uniform_block_ptrs(n, block_size)
 
-    blocks_np, sizes = _extract_blocks_host(A, block_ptrs)
+    with trace.span("block_jacobi.extract", cat="precond"):
+        blocks_np, sizes = _extract_blocks_host(A, block_ptrs)
     nb, bs = blocks_np.shape[0], blocks_np.shape[1]
-    inv = invert_blocks(jnp.asarray(blocks_np))
-    inv_np = np.asarray(inv)
+    with trace.span("block_jacobi.invert", cat="precond"):
+        inv = invert_blocks(jnp.asarray(blocks_np))
+        inv_np = np.asarray(inv)
     base_dtype = inv.dtype
 
-    class_id = _class_ids(adaptive, blocks_np, inv_np, sizes, tau, base_dtype)
-    order = np.argsort(class_id, kind="stable")
+    with trace.span("block_jacobi.classify", cat="precond"):
+        class_id = _class_ids(adaptive, blocks_np, inv_np, sizes, tau, base_dtype)
+        order = np.argsort(class_id, kind="stable")
 
     # gather/scatter maps in class order (host-precomputed, device gathers):
     # row r sits at local slot r - lo of its block, which sits at position
     # pos_of[block] of the class order
-    gather = np.full((nb, bs), n, np.int32)
-    scatter = np.zeros(n, np.int32)
-    pos_of = np.empty(nb, np.int64)
-    pos_of[order] = np.arange(nb)
-    r = np.arange(n, dtype=np.int64)
-    blk = np.repeat(np.arange(nb, dtype=np.int64), sizes)
-    slot, pos = r - block_ptrs[blk], pos_of[blk]
-    gather[pos, slot] = r
-    scatter[r] = pos * bs + slot
+    with trace.span("block_jacobi.maps", cat="precond"):
+        gather = np.full((nb, bs), n, np.int32)
+        scatter = np.zeros(n, np.int32)
+        pos_of = np.empty(nb, np.int64)
+        pos_of[order] = np.arange(nb)
+        r = np.arange(n, dtype=np.int64)
+        blk = np.repeat(np.arange(nb, dtype=np.int64), sizes)
+        slot, pos = r - block_ptrs[blk], pos_of[blk]
+        gather[pos, slot] = r
+        scatter[r] = pos * bs + slot
 
-    classes = _storage_classes(base_dtype)
-    tensors = []
-    sorted_ids = class_id[order]
-    for cid, dtype in enumerate(classes):
-        members = order[sorted_ids == cid]
-        if len(members) == 0:
-            continue
-        tensors.append(jnp.asarray(inv_np[members]).astype(dtype))
+    with trace.span("block_jacobi.upload", cat="precond"):
+        classes = _storage_classes(base_dtype)
+        tensors = []
+        sorted_ids = class_id[order]
+        for cid, dtype in enumerate(classes):
+            members = order[sorted_ids == cid]
+            if len(members) == 0:
+                continue
+            tensors.append(jnp.asarray(inv_np[members]).astype(dtype))
+        gather_idx, scatter_idx = jnp.asarray(gather), jnp.asarray(scatter)
 
     return BlockJacobi(
         inv_blocks=tuple(tensors),
-        gather_idx=jnp.asarray(gather),
-        scatter_idx=jnp.asarray(scatter),
+        gather_idx=gather_idx,
+        scatter_idx=scatter_idx,
         n=n,
         block_size=bs,
         num_blocks=nb,

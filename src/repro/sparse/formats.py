@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.linop import LinOp
+from repro.observability import trace
 
 __all__ = [
     "Coo",
@@ -369,28 +370,29 @@ def csr_from_arrays(indptr, indices, values, shape) -> Csr:
 
 
 def ell_from_csr_host(indptr, indices, values, shape, max_nnz=None) -> Ell:
-    """Host-side CSR -> ELL."""
-    indptr = np.asarray(indptr)
-    indices = np.asarray(indices)
-    values = np.asarray(values)
-    m, _ = shape
-    row_nnz = np.diff(indptr)
-    k = int(max_nnz if max_nnz is not None else (row_nnz.max() if m else 0))
-    k = max(k, 1)
-    bad = np.flatnonzero(row_nnz > k)
-    if bad.size:
-        raise ValueError(
-            f"row {int(bad[0])} has {int(row_nnz[bad[0]])} nnz > max_nnz {k}"
-        )
-    cols = np.zeros((m, k), np.int32)
-    vals = np.zeros((m, k), values.dtype)
-    # vectorized scatter: entry t of the CSR stream lands at
-    # (row[t], t - indptr[row[t]])
-    rows = np.repeat(np.arange(m, dtype=np.int64), row_nnz)
-    pos = np.arange(indices.shape[0], dtype=np.int64) - indptr[:-1][rows]
-    cols[rows, pos] = indices
-    vals[rows, pos] = values
-    return Ell(jnp.asarray(cols), jnp.asarray(vals), tuple(shape))
+    """Host-side CSR -> ELL (span ``sparse.ell_from_csr_host``)."""
+    with trace.span("sparse.ell_from_csr_host", cat="sparse"):
+        indptr = np.asarray(indptr)
+        indices = np.asarray(indices)
+        values = np.asarray(values)
+        m, _ = shape
+        row_nnz = np.diff(indptr)
+        k = int(max_nnz if max_nnz is not None else (row_nnz.max() if m else 0))
+        k = max(k, 1)
+        bad = np.flatnonzero(row_nnz > k)
+        if bad.size:
+            raise ValueError(
+                f"row {int(bad[0])} has {int(row_nnz[bad[0]])} nnz > max_nnz {k}"
+            )
+        cols = np.zeros((m, k), np.int32)
+        vals = np.zeros((m, k), values.dtype)
+        # vectorized scatter: entry t of the CSR stream lands at
+        # (row[t], t - indptr[row[t]])
+        rows = np.repeat(np.arange(m, dtype=np.int64), row_nnz)
+        pos = np.arange(indices.shape[0], dtype=np.int64) - indptr[:-1][rows]
+        cols[rows, pos] = indices
+        vals[rows, pos] = values
+        return Ell(jnp.asarray(cols), jnp.asarray(vals), tuple(shape))
 
 
 def ell_from_dense(a: np.ndarray, dtype=None) -> Ell:

@@ -1,4 +1,4 @@
-"""Structured dispatch events: the log's two faces, shapes, roofline rows."""
+"""Structured dispatch events: the log's two faces, shapes, op scopes."""
 
 import collections
 
@@ -12,7 +12,6 @@ from repro.observability import trace
 from repro.observability.events import (
     DispatchLog,
     make_event,
-    roofline_summary,
     shape_bucket,
     summarize_operands,
 )
@@ -91,23 +90,19 @@ def test_event_carries_resolved_launch_config():
         assert isinstance(launches[0], dict) and launches[0]
 
 
-def test_roofline_summary_aggregates_per_op_space_target():
-    def ev(op, wall, nbytes):
-        return make_event(
-            op=op, space="xla", executor=make_executor("xla"), launch=None,
-            wall_us=wall, ts_us=0.0,
-            operands=[jnp.ones(max(nbytes // 4, 1), jnp.float32)], out=None,
-        )
+def test_traced_dispatch_feeds_no_metric_series():
+    """A traced dispatch records its event and Chrome span, and derives no
+    bandwidth: nothing lands in the metrics registry."""
+    from repro.observability import metrics
 
-    rows = roofline_summary(
-        [ev("a", 10.0, 4000), ev("a", 10.0, 4000), ev("b", 5.0, 1000)],
-        hbm_bandwidth=100e9,
-    )
-    assert [r["op"] for r in rows] == ["a", "b"]
-    ra = rows[0]
-    assert ra["count"] == 2 and ra["est_bytes"] == 8000
-    assert ra["gbs"] == pytest.approx(8000 / 20e-6 / 1e9)
-    assert ra["frac_of_bound"] == pytest.approx(ra["gbs"] / 100.0)
+    metrics.reset()
+    ex = make_executor("xla")
+    tracer = trace.enable()
+    registry.operation("blas_dot")(jnp.ones(8), jnp.ones(8), executor=ex)
+    assert [e.op for e in ex.dispatch_events] == ["blas_dot"]
+    assert [ev["cat"] for ev in tracer.events] == ["dispatch"]
+    assert not hasattr(ex.dispatch_events[0], "gbs")
+    assert metrics.samples() == []
 
 
 def test_event_deque_is_bounded():

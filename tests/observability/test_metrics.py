@@ -1,9 +1,8 @@
-"""Metrics registry: series identity, kinds, exporters, dispatch folding."""
+"""Metrics registry: series identity, kinds, exporters."""
 
 import pytest
 
 from repro.observability import metrics
-from repro.observability.events import DispatchEvent
 
 
 @pytest.fixture(autouse=True)
@@ -116,31 +115,3 @@ def test_jsonl_roundtrip_and_table(tmp_path):
     assert metrics.render_table() != "(no metrics recorded)"
     metrics.reset()
     assert metrics.render_table() == "(no metrics recorded)"
-
-
-def _event(wall_us=10.0, est_bytes=8000):
-    return DispatchEvent(
-        op="spmv_csr", space="xla", executor="XlaExecutor", target="cpu_xla",
-        shapes=((8,), (8, 8)), shape_bucket=64, launch=None,
-        wall_us=wall_us, est_bytes=est_bytes, ts_us=0.0,
-    )
-
-
-def test_observe_dispatch_folds_counters_and_gauges():
-    labels = dict(op="spmv_csr", space="xla", target="cpu_xla")
-    metrics.observe_dispatch(_event(), hbm_bandwidth=100e9)
-    metrics.observe_dispatch(_event(wall_us=5.0), hbm_bandwidth=100e9)
-    assert metrics.counter("dispatch_total", **labels).value == 2
-    assert metrics.histogram("dispatch_wall_us", **labels).count == 2
-    # last event: 8000 B / 5 us = 1.6 GB/s; bound 100 GB/s -> 0.016
-    assert metrics.gauge("dispatch_gbs", **labels).value == pytest.approx(1.6)
-    assert metrics.gauge(
-        "dispatch_frac_of_bound", **labels
-    ).value == pytest.approx(0.016)
-
-
-def test_observe_dispatch_without_bytes_skips_gauges():
-    metrics.observe_dispatch(_event(est_bytes=0))
-    names = {r["name"] for r in metrics.samples()}
-    assert "dispatch_gbs" not in names
-    assert "dispatch_total" in names
