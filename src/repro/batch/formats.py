@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.batch.linop import BatchLinOp
-from repro.sparse.formats import Csr, Ell, _nbytes
+from repro.sparse.formats import Csr, Ell, _nbytes, ell_packed
 
 __all__ = [
     "BatchCsr",
@@ -238,8 +238,11 @@ def batch_ell_from_list(mats: Sequence[Ell]) -> BatchEll:
 
     Identical column blocks take the fast path; otherwise each row's union
     column set (padded to the batch-wide max width) becomes the shared block.
+    Diagonal-aligned inputs are left-packed first: the batch slot tables
+    expect a row's padding at its tail.
     """
     shape = _check_uniform_shapes(mats)
+    mats = [ell_packed(m) for m in mats]
     if _shared_ell_pattern(mats):
         return BatchEll(
             col_idx=mats[0].col_idx,

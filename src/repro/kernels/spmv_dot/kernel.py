@@ -10,6 +10,7 @@ the same pass when given ``w``; this module is its fused entry point.
 from __future__ import annotations
 
 import functools
+from typing import Optional, Tuple
 
 import jax
 
@@ -17,7 +18,7 @@ from repro.kernels.spmv_ell.kernel import ell_apply
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_m", "block_k", "interpret")
+    jax.jit, static_argnames=("offsets", "block_m", "block_k", "interpret")
 )
 def spmv_dot_ell(
     col_idx: jax.Array,
@@ -25,12 +26,13 @@ def spmv_dot_ell(
     x: jax.Array,
     w: jax.Array,
     *,
+    offsets: Optional[Tuple[int, ...]] = None,
     block_m: int = 8192,
     block_k: int = 32,
     interpret: bool = False,
 ):
     """(y, w·y) = (A @ x, dot) for ELL-format A of shape (m, k), one pass."""
     return ell_apply(
-        col_idx, values, x, w,
+        col_idx, values, x, w, offsets=offsets,
         block_m=block_m, block_k=block_k, interpret=interpret,
     )

@@ -56,6 +56,7 @@ def _spmv_dot_ell_skeleton(ex, A: Ell, x, w, *, variant: str):
         A.values,
         x,
         w,
+        offsets=A.offsets,
         block_m=cfg["block_m"],
         block_k=cfg["block_k"],
         interpret=ex.interpret,

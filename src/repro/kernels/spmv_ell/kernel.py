@@ -1,12 +1,19 @@
 """ELL SpMV Pallas TPU kernel, with an optional fused dot epilogue.
 
-XLA gathers ``x[col_idx]`` outside the kernel; the kernel streams the values
-and the gathered entries and reduces each row.  Both operands are laid out
-slot-major and lane-dense — ``(k, rows, 128)``, row ``r`` of the matrix at
-``[:, r // 128, r % 128]`` (:mod:`repro.kernels.lanes`) — so the row
-reduction is an elementwise sum over the leading ``k`` axis and the output
-``y`` is a lane-dense ``(rows, 128)`` view.  This is Ginkgo's column-major
-ELL storage: consecutive rows sit in consecutive lanes.
+XLA builds the ``x`` operand outside the kernel; the kernel streams the
+values and the matching ``x`` entries and reduces each row.  Both operands
+are laid out slot-major and lane-dense — ``(k, rows, 128)``, row ``r`` of the
+matrix at ``[:, r // 128, r % 128]`` (:mod:`repro.kernels.lanes`) — so the
+row reduction is an elementwise sum over the leading ``k`` axis and the
+output ``y`` is a lane-dense ``(rows, 128)`` view.  This is Ginkgo's
+column-major ELL storage: consecutive rows sit in consecutive lanes.
+
+The operand is produced one of two ways, by the matrix's layout
+(:class:`repro.sparse.formats.Ell`).  Left-packed slots gather
+``x[col_idx]``.  Diagonal-aligned slots (``offsets`` given) need no gather:
+slot ``q`` of every row reads ``x[r + offsets[q]]``, a contiguous slice of
+``x`` zero-padded at both ends, and ``col_idx`` is not read.  That path's
+``pallas_call`` is named ``spmv_ell_band`` / ``spmv_dot_ell_band``.
 
 Grid = (row blocks, k blocks), k innermost; partial sums accumulate in the
 revisited output block (TPU grids iterate in order, so the read-modify-write
@@ -22,7 +29,7 @@ carry ``w = 0`` and value 0.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -67,17 +74,31 @@ def _ell_kernel(vals_ref, xg_ref, *refs, dot: bool):
         ).astype(d_ref.dtype)
 
 
+def _shifted_slabs(x, offsets, rows: int, pk: int):
+    """``(pk, rows, 128)`` operand of diagonal-aligned slots: slab ``q``
+    holds ``x[r + offsets[q]]`` at row ``r`` (0 outside ``x``), slabs past
+    ``len(offsets)`` are zero."""
+    n, span = x.shape[0], rows * lanes.LANES
+    lo = max(-min(offsets), 0)
+    xpad = jnp.pad(x, (lo, max(max(offsets), 0) + span - n))
+    slabs = [jax.lax.slice_in_dim(xpad, lo + d, lo + d + span) for d in offsets]
+    slabs += [jnp.zeros(span, x.dtype)] * (pk - len(offsets))
+    return jnp.stack(slabs).reshape(pk, rows, lanes.LANES)
+
+
 def ell_apply(
     col_idx: jax.Array,
     values: jax.Array,
     x: jax.Array,
     w: Optional[jax.Array] = None,
     *,
+    offsets: Optional[Tuple[int, ...]] = None,
     block_m: int,
     block_k: int,
     interpret: bool,
 ):
-    """``y = A @ x`` (and ``w · y`` when ``w`` is given) for ELL-format A."""
+    """``y = A @ x`` (and ``w · y`` when ``w`` is given) for ELL-format A;
+    ``offsets`` are the diagonals of aligned slots (``Ell.offsets``)."""
     m, k = values.shape
     dtype = jnp.result_type(values.dtype, x.dtype)
     dot = w is not None
@@ -85,12 +106,19 @@ def ell_apply(
     block_k = max(min(block_k, k), 1)
     pk = pl.cdiv(k, block_k) * block_k
     # slot-major, lane-dense operands; padding is (col 0, value 0)
-    cols_t = lanes.to_rows(col_idx.T, rows)
     vals_t = lanes.to_rows(values.T, rows)
     if pk != k:
-        cols_t = jnp.pad(cols_t, ((0, pk - k), (0, 0), (0, 0)))
         vals_t = jnp.pad(vals_t, ((0, pk - k), (0, 0), (0, 0)))
-    xg = x[cols_t]  # the gather stays in XLA
+    if offsets is not None:
+        xg = _shifted_slabs(x, offsets, rows, pk)
+    else:
+        cols_t = lanes.to_rows(col_idx.T, rows)
+        if pk != k:
+            cols_t = jnp.pad(cols_t, ((0, pk - k), (0, 0), (0, 0)))
+        xg = x[cols_t]  # the gather stays in XLA
+    name = ("spmv_dot_ell" if dot else "spmv_ell") + (
+        "" if offsets is None else "_band"
+    )
 
     slab = pl.BlockSpec((block_k, block_rows, lanes.LANES), lambda i, j: (j, i, 0))
     vec = pl.BlockSpec((block_rows, lanes.LANES), lambda i, j: (i, 0))
@@ -107,7 +135,7 @@ def ell_apply(
     itemsize = max(jnp.dtype(values.dtype).itemsize, jnp.dtype(dtype).itemsize)
     outs = pl.pallas_call(
         functools.partial(_ell_kernel, dot=dot),
-        name="spmv_dot_ell" if dot else "spmv_ell",
+        name=name,
         grid=(rows // block_rows, pk // block_k),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -124,13 +152,14 @@ def ell_apply(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_m", "block_k", "interpret")
+    jax.jit, static_argnames=("offsets", "block_m", "block_k", "interpret")
 )
 def spmv_ell(
     col_idx: jax.Array,
     values: jax.Array,
     x: jax.Array,
     *,
+    offsets: Optional[Tuple[int, ...]] = None,
     block_m: int = 8192,
     block_k: int = 32,
     interpret: bool = False,
@@ -138,8 +167,10 @@ def spmv_ell(
     """y = A @ x for ELL-format A given as (col_idx, values) of shape (m, k).
 
     ``block_m`` rows (a multiple of 1024 = 8 sublanes x 128 lanes, rounded
-    down) and ``block_k`` ELL slots are streamed per grid step.
+    down) and ``block_k`` ELL slots are streamed per grid step; ``offsets``
+    (``Ell.offsets``) selects the gather-free path of aligned slots.
     """
     return ell_apply(
-        col_idx, values, x, block_m=block_m, block_k=block_k, interpret=interpret
+        col_idx, values, x, offsets=offsets,
+        block_m=block_m, block_k=block_k, interpret=interpret,
     )

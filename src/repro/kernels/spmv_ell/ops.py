@@ -74,6 +74,7 @@ def _spmv_ell_skeleton(ex, A: Ell, x, *, variant: str):
         A.col_idx,
         A.values,
         x,
+        offsets=A.offsets,
         block_m=cfg["block_m"],
         block_k=cfg["block_k"],
         interpret=ex.interpret,

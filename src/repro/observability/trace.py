@@ -94,6 +94,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def annotate(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -127,6 +130,10 @@ class _Span:
             self.annotation.__enter__()
         self.start_us = self.tracer.now_us()
         return self
+
+    def annotate(self, **args) -> None:
+        """Add ``args`` to the event, for what is known only inside the span."""
+        self.args.update(args)
 
     def __exit__(self, *exc):
         end = self.tracer.now_us()

@@ -9,6 +9,16 @@ TPU adaptations (DESIGN.md §2):
 
 * ELL stores row-major ``(m, max_nnz)`` blocks; padding uses column 0 with a
   zero value so gathers stay in-bounds without predication.
+* ELL slots follow one of two layouts, picked by the pattern alone.  A square
+  pattern whose entries lie on at most ``max_nnz`` distinct diagonals
+  (offsets ``col - row``, as in a stencil or a banded matrix), each row's
+  columns strictly ascending, stores the entry of offset ``offsets[q]`` in
+  slot ``q`` of every row; a row that lacks that diagonal keeps the padding
+  ``(col 0, value 0)`` there, and ``Ell.offsets`` records the ascending
+  offsets, so the SpMV can read ``x`` as shifted contiguous slices instead of
+  gathering it.  Any other pattern is left-packed: each row's entries in its
+  first slots in CSR order, padding at the tail, ``Ell.offsets is None``.
+  Both store ``(m, max_nnz)``.
 * SELL-P uses slice size ``C = 8`` (one sublane) by default instead of
   Ginkgo's GPU default 64, and pads each slice's column count to a multiple of
   ``stride_factor`` so slice-local blocks stay vector-aligned.  Values are laid
@@ -20,14 +30,14 @@ TPU adaptations (DESIGN.md §2):
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.linop import LinOp
-from repro.observability import trace
+from repro.observability import metrics, trace
 
 __all__ = [
     "Coo",
@@ -38,6 +48,7 @@ __all__ = [
     "convert",
     "csr_host_arrays",
     "csr_slice_rows_host",
+    "ell_packed",
 ]
 
 
@@ -227,11 +238,21 @@ class Ell(MatrixLinOp):
 
     Padding entries have ``col_idx == 0`` and ``value == 0`` (in-bounds gather,
     zero contribution) — the predication-free TPU idiom.
+
+    ``offsets`` (static) is ``None`` for the left-packed layout: a row's
+    entries fill its first slots and the padding sits at the tail.  When it
+    is a tuple, the slots are diagonal-aligned (:func:`ell_from_csr_host`
+    picks this for a square pattern with at most ``max_nnz`` distinct
+    offsets): slot ``q`` of row ``r`` holds column ``r + offsets[q]``, or the
+    padding where the row lacks that diagonal, and slots past
+    ``len(offsets)`` are padding in every row.  ``offsets`` ascend, so each
+    row's entries stay in ascending column order.
     """
 
     col_idx: jax.Array  # (m, max_nnz) int32
     values: jax.Array  # (m, max_nnz)
     shape: Tuple[int, int]
+    offsets: Optional[Tuple[int, ...]] = None
 
     @property
     def max_nnz(self) -> int:
@@ -252,7 +273,7 @@ class Ell(MatrixLinOp):
         return _nbytes(self.col_idx, self.values)
 
 
-_register(Ell, ["col_idx", "values"], ["shape"])
+_register(Ell, ["col_idx", "values"], ["shape", "offsets"])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -369,13 +390,39 @@ def csr_from_arrays(indptr, indices, values, shape) -> Csr:
     )
 
 
+def _rows_ascend(indptr, indices) -> bool:
+    """Whether each row's column indices strictly ascend."""
+    up = np.diff(indices) > 0
+    starts = indptr[1:-1]
+    # a step from one row's last entry to the next row's first may go down
+    up[starts[(starts > 0) & (starts < indices.size)] - 1] = True
+    return bool(up.all())
+
+
+def _ell_arrays(filled: np.ndarray, indices, values):
+    """``(cols, vals)`` holding the CSR entries, in order, in the ``True``
+    slots of ``filled`` (row-major); the other slots hold the padding."""
+    cols = np.zeros(filled.shape, np.int32)
+    vals = np.zeros(filled.shape, values.dtype)
+    cols[filled] = indices
+    vals[filled] = values
+    return cols, vals
+
+
 def ell_from_csr_host(indptr, indices, values, shape, max_nnz=None) -> Ell:
-    """Host-side CSR -> ELL (span ``sparse.ell_from_csr_host``)."""
-    with trace.span("sparse.ell_from_csr_host", cat="sparse"):
+    """Host-side CSR -> ELL (span ``sparse.ell_from_csr_host``).
+
+    Diagonal-aligned slots when the pattern is square, each row's columns
+    strictly ascend, and it has at most ``max_nnz`` distinct offsets
+    ``col - row``; the left-packed layout otherwise (module docstring).
+    Each conversion counts its layout in
+    ``sparse.ell_layout{layout=band|packed}``.
+    """
+    with trace.span("sparse.ell_from_csr_host", cat="sparse") as span:
         indptr = np.asarray(indptr)
         indices = np.asarray(indices)
         values = np.asarray(values)
-        m, _ = shape
+        m, n = shape
         row_nnz = np.diff(indptr)
         k = int(max_nnz if max_nnz is not None else (row_nnz.max() if m else 0))
         k = max(k, 1)
@@ -384,15 +431,46 @@ def ell_from_csr_host(indptr, indices, values, shape, max_nnz=None) -> Ell:
             raise ValueError(
                 f"row {int(bad[0])} has {int(row_nnz[bad[0]])} nnz > max_nnz {k}"
             )
-        cols = np.zeros((m, k), np.int32)
-        vals = np.zeros((m, k), values.dtype)
-        # vectorized scatter: entry t of the CSR stream lands at
-        # (row[t], t - indptr[row[t]])
-        rows = np.repeat(np.arange(m, dtype=np.int64), row_nnz)
-        pos = np.arange(indices.shape[0], dtype=np.int64) - indptr[:-1][rows]
-        cols[rows, pos] = indices
-        vals[rows, pos] = values
-        return Ell(jnp.asarray(cols), jnp.asarray(vals), tuple(shape))
+        offsets = None
+        # a canonical pattern (each row's columns strictly ascending, so no
+        # entry repeats and no two share a slot) on few diagonals
+        if m == n and indices.size and _rows_ascend(indptr, indices):
+            rows = np.repeat(np.arange(m, dtype=np.int64), row_nnz)
+            diag = indices - rows
+            diag += n - 1  # offset col - row as a bin in [0, 2n - 1)
+            present = np.bincount(diag) > 0
+            if np.count_nonzero(present) <= k:
+                offsets = np.flatnonzero(present) - (n - 1)
+                # an entry's slot is its offset's rank among the offsets
+                filled = np.zeros((m, k), bool)
+                filled[rows, (np.cumsum(present) - 1)[diag]] = True
+        if offsets is None:
+            filled = np.arange(k) < row_nnz[:, None]
+        cols, vals = _ell_arrays(filled, indices, values)
+        layout = "packed" if offsets is None else "band"
+        metrics.counter("sparse.ell_layout", layout=layout).inc()
+        span.annotate(layout=layout, offsets=0 if offsets is None else offsets.size)
+        return Ell(
+            jnp.asarray(cols),
+            jnp.asarray(vals),
+            tuple(shape),
+            None if offsets is None else tuple(int(o) for o in offsets),
+        )
+
+
+def ell_packed(A: Ell) -> Ell:
+    """``A`` in the left-packed layout (``A`` itself when it already is).
+
+    For consumers whose slot tables assume a row's padding at its tail
+    (:class:`repro.batch.BatchEll`).  Explicit stored zeros are dropped, as in
+    :func:`csr_host_arrays`; the width stays ``A.max_nnz``.
+    """
+    if A.offsets is None:
+        return A
+    indptr, indices, values = csr_host_arrays(A)
+    filled = np.arange(A.max_nnz) < np.diff(indptr)[:, None]
+    cols, vals = _ell_arrays(filled, indices, values)
+    return Ell(jnp.asarray(cols), jnp.asarray(vals), A.shape)
 
 
 def ell_from_dense(a: np.ndarray, dtype=None) -> Ell:

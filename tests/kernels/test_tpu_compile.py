@@ -24,6 +24,7 @@ from repro.core import PallasTpuExecutor, params as hw_params, registry
 M = 128 ** 3  # rows of poisson_3d(128)
 K = 7  # 7-point stencil
 BS = 8  # block-Jacobi block size
+OFFSETS = (-128 * 128, -128, -1, 0, 1, 128, 128 * 128)  # its diagonals
 
 
 @pytest.fixture(scope="module")
@@ -57,13 +58,14 @@ def _sds(one_chip, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
-def _ell(one_chip):
+def _ell(one_chip, offsets=None):
     from repro.sparse import Ell
 
     return Ell(
         col_idx=_sds(one_chip, (M, K), jnp.int32),
         values=_sds(one_chip, (M, K)),
         shape=(M, M),
+        offsets=offsets,
     )
 
 
@@ -84,6 +86,27 @@ def test_spmv_dot_ell_compiles(one_chip, ex):
     assert op.space_used(ex) == "pallas"
     vec = _sds(one_chip, (M,))
     _compile(lambda A, x, w: op(A, x, w, executor=ex), _ell(one_chip), vec, vec)
+
+
+def test_spmv_ell_band_compiles(one_chip, ex):
+    """Diagonal-aligned slots: shifted slices of ``x``, no gather."""
+    op = registry.operation("spmv_ell")
+    text = _compile(
+        lambda A, x: op(A, x, executor=ex),
+        _ell(one_chip, OFFSETS),
+        _sds(one_chip, (M,)),
+    )
+    assert "spmv_ell_band" in text
+
+
+def test_spmv_dot_ell_band_compiles(one_chip, ex):
+    op = registry.operation("spmv_dot_ell")
+    vec = _sds(one_chip, (M,))
+    text = _compile(
+        lambda A, x, w: op(A, x, w, executor=ex),
+        _ell(one_chip, OFFSETS), vec, vec,
+    )
+    assert "spmv_dot_ell_band" in text
 
 
 def test_axpy_norm_compiles(one_chip, ex):
