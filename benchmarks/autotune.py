@@ -260,38 +260,6 @@ def _spmv_sellp_runner(ex):
     return shapes, run
 
 
-def _spgemm_runner(ex):
-    from repro.kernels.spgemm.kernel import spgemm_expand
-    from repro.sparse.formats import csr_from_arrays
-    from repro.sparse.gallery import poisson_2d
-    from repro.sparse.ops import _spgemm_maps
-
-    indptr, indices, values, shape = poisson_2d(32)
-    A = csr_from_arrays(indptr, indices, values.astype(np.float32), shape)
-    # representative A·A workload: host structure pass once, then time only
-    # the numeric expansion the candidate geometry actually tiles
-    rows_a, b_start, b_len, K = _spgemm_maps(A, A)
-    q = np.arange(K)
-    valid = q[None, :] < b_len[:, None]
-    idx1 = jnp.asarray(
-        np.where(valid, b_start[:, None] + q[None, :] + 1, 0).astype(np.int32)
-    )
-    b_pad = jnp.concatenate([jnp.zeros(1, A.values.dtype), A.values])
-    shapes = {"t": rows_a.size, "k": K, "nnzb": A.nnz, "itemsize": 4}
-
-    def run(block):
-        return time_fn(
-            lambda: spgemm_expand(
-                A.values, idx1, b_pad,
-                block_t=block["block_t"], block_k=block["block_k"],
-                interpret=ex.interpret,
-            ),
-            warmup=1, repeats=3,
-        )
-
-    return shapes, run
-
-
 def _block_jacobi_runner(ex):
     from repro.kernels.block_jacobi.kernel import block_jacobi_apply
 
@@ -357,7 +325,6 @@ RUNNERS: Dict[str, tuple] = {
     "axpy_norm": (_axpy_norm_runner, ("pallas",)),
     "spmv_sellp": (_spmv_sellp_runner, ("pallas",)),
     "spmv_batch_ell": (_spmv_batch_ell_runner, ("pallas",)),
-    "spgemm": (_spgemm_runner, ("pallas",)),
     "block_jacobi": (_block_jacobi_runner, ("pallas",)),
 }
 

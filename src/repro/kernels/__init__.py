@@ -15,7 +15,6 @@ import repro.kernels.block_jacobi.ops  # noqa: F401
 import repro.kernels.flash_attention.ops  # noqa: F401
 import repro.kernels.rmsnorm.ops  # noqa: F401
 import repro.kernels.rwkv6.ops  # noqa: F401
-import repro.kernels.spgemm.ops  # noqa: F401
 import repro.kernels.spmv_batch_ell.ops  # noqa: F401
 import repro.kernels.spmv_dot.ops  # noqa: F401
 import repro.kernels.spmv_ell.ops  # noqa: F401
@@ -27,7 +26,6 @@ from repro.kernels.block_jacobi.kernel import block_jacobi_apply
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.rmsnorm.kernel import rmsnorm
 from repro.kernels.rwkv6.kernel import rwkv6_scan, rwkv6_scan_log
-from repro.kernels.spgemm.kernel import csr_permute, spgemm_expand
 from repro.kernels.spmv_batch_ell.kernel import spmv_batch_ell
 from repro.kernels.spmv_dot.kernel import spmv_dot_ell
 from repro.kernels.spmv_ell.kernel import spmv_ell
@@ -37,8 +35,6 @@ from repro.kernels.ssd.kernel import ssd_scan
 __all__ = [
     "axpy_norm",
     "block_jacobi_apply",
-    "csr_permute",
-    "spgemm_expand",
     "flash_attention",
     "rmsnorm",
     "rwkv6_scan",

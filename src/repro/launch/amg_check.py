@@ -61,10 +61,9 @@ def run_amg_check(
                                 cycle=cycle, theta=theta)
     setup_s = time.perf_counter() - t0
     rows = [int(L.A.shape[0]) for L in M_amg.levels]
-    nnzs = [int(np.asarray(L.A.indices).size) for L in M_amg.levels]
-    complexity = sum(nnzs) / max(nnzs[0], 1)
     print(f"  hierarchy: {M_amg.num_levels} levels, rows {rows}, "
-          f"operator complexity {complexity:.2f}, setup {setup_s:.2f} s")
+          f"operator complexity {M_amg.operator_complexity:.2f}, "
+          f"setup {setup_s:.2f} s")
 
     M_bj = make_preconditioner(A, "block_jacobi", executor=ex)
 

@@ -1,35 +1,58 @@
-"""Algebraic multigrid — smoothed aggregation on the SpGEMM kernel family.
+"""Algebraic multigrid — smoothed aggregation on the registered SpGEMM ops.
 
-The ``gko::multigrid`` analogue (arXiv:2006.16852 §solvers): on PDE-like
-matrices, Krylov iteration counts grow with √κ, and AMG is the O(√κ) → O(1)
-jump — a hierarchy of coarse operators built *algebraically* from the matrix,
-each level damping the error frequencies its smoother can see.
+Smoothed aggregation (Vaněk, Mandel and Brezina, 1996) used as one cycle of
+a preconditioner, as Ginkgo uses ``gko::solver::Multigrid``: on PDE-like
+matrices, Krylov iteration counts grow with √κ, and multigrid is the
+O(√κ) → O(1) jump — a hierarchy of coarse operators built *algebraically*
+from the matrix, each level damping the error frequencies its smoother can
+see.  Ginkgo's own coarsening, ``gko::multigrid::Pgm``, is pairwise
+aggregation with an unsmoothed prolongator; this module aggregates whole
+strong neighbourhoods and smooths the prolongator instead, which is what
+buys grid-independent convergence.
 
-Setup pipeline (all sparse-sparse composition through the registered
-``spgemm`` / ``sptranspose`` ops, so it runs in whichever kernel space the
-executor selects):
+Setup pipeline (host structure, with every sparse-sparse composition
+through the registered ``spgemm`` / ``sptranspose`` ops, so it runs in
+whichever kernel space the executor selects):
 
   1. strength-of-connection — entry (i, j) is *strong* when
      ``|a_ij| ≥ θ·√(a_ii·a_jj)`` (the classical SA filter; anisotropic
      problems drop their weak direction here);
-  2. greedy aggregation — 3 passes: seed aggregates around rows whose strong
-     neighborhood is untouched, attach leftovers to a neighboring aggregate,
-     sweep singletons;
+  2. greedy aggregation in row order — 3 passes: seed aggregates around
+     rows whose strong neighborhood is untouched, attach leftovers to a
+     neighboring aggregate, sweep singletons;
   3. tentative prolongator ``T`` (one unit entry per row: fine point → its
      aggregate), optionally *smoothed* — ``P = (I − ω·D⁻¹A)·T`` via one
-     SpGEMM — which is what buys grid-independent convergence;
+     SpGEMM;
   4. Galerkin triple product ``A_c = R·A·P`` with ``R = Pᵀ`` — two SpGEMMs
      and one sparse transpose.
 
-The cycle (V or W) runs weighted-Jacobi or block-Jacobi smoothers per level
-and a dense-inverse (default) or CG coarse solve; the recursion is unrolled
-at trace time, so :meth:`Multigrid._apply` is jit-traceable and works inside
-``lax.while_loop`` — the requirement for serving as ``M`` in every Krylov
-solver through :func:`repro.precond.make_preconditioner` (``M="amg"``).
+The descent ends at ``coarse_size`` rows, at ``max_levels``, or at a level
+whose aggregation would keep more than half of its rows (coarsening that
+stalls only makes the next level denser).  The coarsest level is inverted
+densely in float64 on the host, or solved by CG; a coarsest level above
+:data:`DENSE_COARSE_MAX_ROWS` rows is refused for the dense inverse.
 
-Setup emits ``amg.setup`` / ``amg.level`` dispatch-trace spans and per-level
-``amg_level_rows`` / ``amg_level_nnz`` gauges plus the operator complexity
-(Σ level nnz / fine nnz) — the standard AMG cost metric.
+The operand may be CSR or ELL, and the setup reads its host pattern and
+values (:func:`repro.sparse.formats.csr_host_arrays`).  The fine level
+applies an ELL operand as handed and converts a CSR one to ELL once; coarse
+operators and the transfers are ELL too.
+
+The cycle (V or W) runs weighted-Jacobi or block-Jacobi smoothers per level
+and the coarse solve; the recursion is unrolled at trace time, so
+:meth:`Multigrid._apply` is jit-traceable and works inside
+``lax.while_loop``, and :class:`Multigrid` is a pytree, so a jitted solve
+takes it as an argument — the requirement for serving as ``M`` in every
+Krylov solver through :func:`repro.precond.make_preconditioner`
+(``M="amg"``).  Inside ``Multigrid.apply`` the cycle names its parts on the
+device trace: ``Multigrid.level<k>`` (level k's smoothing and residual),
+``Multigrid.restrict<k>`` / ``Multigrid.prolong<k>`` (the transfers between
+level k and k+1) and ``Multigrid.coarse``.
+
+Setup emits ``amg.setup`` / ``amg.level`` / ``amg.coarse_solver`` spans and
+the gauges ``amg_level_rows{level}``, ``amg_level_nnz{level}``,
+``amg_transfer_nnz{level}`` (stored nonzeros of ``P_k`` and ``R_k``) and
+``amg_operator_complexity`` (Σ level nnz / fine nnz) — the standard AMG
+cost metric.
 
 The serve layer uses the pattern-only subset at the bottom of this module:
 aggregation from the sparsity pattern alone plus an additive two-level
@@ -56,9 +79,10 @@ from repro.sparse.formats import (
     csr_host_arrays,
     ell_from_csr_host,
 )
-from repro.sparse.ops import _coalesce_host, apply as sp_apply, spgemm, sptranspose, to_dense
+from repro.sparse.ops import _coalesce_host, apply as sp_apply, spgemm, sptranspose
 
 __all__ = [
+    "DENSE_COARSE_MAX_ROWS",
     "AmgLevel",
     "AmgServePattern",
     "Multigrid",
@@ -70,6 +94,9 @@ __all__ = [
     "strength_mask",
     "tentative_prolongator",
 ]
+
+#: the largest coarsest level the dense inverse takes (256 MiB in float32)
+DENSE_COARSE_MAX_ROWS = 8192
 
 
 # =============================================================================
@@ -187,25 +214,34 @@ class AmgLevel:
     smoother data (inverse diagonal for weighted Jacobi, or a block-Jacobi
     LinOp when the hierarchy was built with ``smoother="block_jacobi"``).
 
-    The CSR forms are what the Galerkin composition produced (and what tests
-    introspect); the ``*_op`` ELL mirrors are what the cycle *applies* — PDE
-    hierarchies have near-uniform row counts, and the ELL SpMV needs no
-    per-apply row-id reconstruction, which is what keeps the V-cycle's
-    per-iteration cost within a small factor of one fine-grid SpMV.
+    ``A`` is what the cycle applies, always ELL: on level 0 the operand
+    :class:`Multigrid` was handed (converted once if it came as CSR), below
+    it the Galerkin product.  The
+    transfers ``P`` (coarse → fine) and ``R = Pᵀ`` (fine → coarse) are ELL
+    too — PDE hierarchies have near-uniform row counts, and the ELL SpMV
+    needs no per-apply row-id reconstruction.
     """
 
-    A: Csr
-    P: Csr  # prolongation: coarse -> fine
-    R: Csr  # restriction:  fine -> coarse (Pᵀ)
-    A_op: Ell
-    P_op: Ell
-    R_op: Ell
+    A: Ell
+    P: Ell
+    R: Ell
     inv_diag: jax.Array
     smoother: Optional[LinOp] = None
 
 
+# a pytree, so the hierarchy travels as an argument of a jitted solve
+jax.tree_util.register_dataclass(
+    AmgLevel,
+    data_fields=["A", "P", "R", "inv_diag", "smoother"],
+    meta_fields=[],
+)
+
+
 class Multigrid(LinOp):
-    """AMG V/W-cycle as a LinOp (gko::multigrid::Pgm + gko::solver::Multigrid).
+    """Smoothed-aggregation AMG V/W-cycle as a LinOp (the analogue of
+    ``gko::solver::Multigrid`` used as a preconditioner; smoothed
+    aggregation where Ginkgo's ``gko::multigrid::Pgm`` aggregates pairwise
+    and leaves the prolongator unsmoothed).
 
     ``apply(r)`` runs one cycle from a zero initial guess — i.e. it is the
     preconditioner application ``M⁻¹ r``.  The cycle recursion is unrolled at
@@ -217,7 +253,7 @@ class Multigrid(LinOp):
 
     def __init__(
         self,
-        A: Csr,
+        A,
         *,
         theta: float = 0.08,
         omega: float = 2.0 / 3.0,
@@ -251,80 +287,81 @@ class Multigrid(LinOp):
         self._dtype = A.values.dtype
         self.levels: List[AmgLevel] = []
 
-        fine_nnz = max(A.nnz, 1)
-        with trace.span("amg.setup", cat="amg", n=A.shape[0], nnz=A.nnz,
+        indptr, indices, values = csr_host_arrays(A)
+        n = A.shape[0]
+        fine_nnz = max(indices.size, 1)
+        total_nnz = 0
+        with trace.span("amg.setup", cat="amg", n=n, nnz=indices.size,
                         theta=theta, cycle=cycle):
+            # the level's operator, as the cycle applies it: an ELL operand as
+            # handed, a CSR one converted to ELL once
+            op = A if isinstance(A, Ell) else ell_from_csr_host(
+                indptr, indices, values, A.shape)
             level = 0
-            while A.shape[0] > coarse_size and level < max_levels:
-                indptr, indices, values = csr_host_arrays(A)
-                n = A.shape[0]
+            while n > coarse_size and level < max_levels:
                 strong = strength_mask(indptr, indices, values, theta)
                 agg, n_agg = aggregate(indptr, indices, strong, n)
-                if n_agg >= n:
-                    break  # coarsening stalled — stop descending
+                if 2 * n_agg > n:
+                    break  # coarsening stalls: this level is the coarsest
                 with trace.span("amg.level", cat="amg", level=level,
-                                rows=n, nnz=A.nnz, coarse_rows=n_agg):
+                                rows=n, nnz=indices.size, coarse_rows=n_agg):
+                    A_csr = csr_from_arrays(indptr, indices, values, (n, n))
                     T = tentative_prolongator(agg, n_agg)
+                    diag = _csr_diag(indptr, indices, values, n)
+                    inv_d = np.where(diag != 0, 1.0 / diag, 0.0).astype(
+                        values.dtype
+                    )
                     if smooth_prolongator:
-                        diag = _csr_diag(indptr, indices, values, n)
-                        inv_d = np.where(diag != 0, 1.0 / diag, 0.0).astype(
-                            values.dtype
-                        )
-                        AT = spgemm(A, T, executor=executor)
+                        AT = spgemm(A_csr, T, executor=executor)
                         P = _csr_sub_scaled(T, AT, self.omega * inv_d)
                     else:
                         P = T
                     R = sptranspose(P, executor=executor)
-                    A_c = spgemm(R, spgemm(A, P, executor=executor),
+                    A_c = spgemm(R, spgemm(A_csr, P, executor=executor),
                                  executor=executor)
-                diag = _csr_diag(indptr, indices, values, n)
-                inv_diag = jnp.asarray(
-                    np.where(diag != 0, 1.0 / diag, 0.0).astype(values.dtype)
-                )
-                sm = None
-                if smoother == "block_jacobi":
-                    from repro.precond.block_jacobi import block_jacobi
+                    sm = None
+                    if smoother == "block_jacobi":
+                        from repro.precond.block_jacobi import block_jacobi
 
-                    sm = block_jacobi(
-                        A, executor=executor, **(smoother_opts or {})
+                        sm = block_jacobi(
+                            op, executor=executor, **(smoother_opts or {})
+                        )
+                    self.levels.append(
+                        AmgLevel(A=op, P=_ell_of(P), R=_ell_of(R),
+                                 inv_diag=jnp.asarray(inv_d), smoother=sm)
                     )
-                self.levels.append(
-                    AmgLevel(
-                        A=A, P=P, R=R,
-                        A_op=_ell_of(A), P_op=_ell_of(P), R_op=_ell_of(R),
-                        inv_diag=inv_diag, smoother=sm,
-                    )
-                )
                 metrics.gauge("amg_level_rows", level=level).set(n)
-                metrics.gauge("amg_level_nnz", level=level).set(A.nnz)
-                A = A_c
+                metrics.gauge("amg_level_nnz", level=level).set(indices.size)
+                metrics.gauge("amg_transfer_nnz", level=level).set(P.nnz + R.nnz)
+                total_nnz += indices.size
+                indptr, indices, values = csr_host_arrays(A_c)
+                op = ell_from_csr_host(indptr, indices, values, A_c.shape)
+                n = n_agg
                 level += 1
 
-            self.coarse_A = A
-            metrics.gauge("amg_level_rows", level=level).set(A.shape[0])
-            metrics.gauge("amg_level_nnz", level=level).set(A.nnz)
-            total_nnz = sum(l.A.nnz for l in self.levels) + A.nnz
-            self.operator_complexity = total_nnz / fine_nnz
+            self.coarse_A = op
+            metrics.gauge("amg_level_rows", level=level).set(n)
+            metrics.gauge("amg_level_nnz", level=level).set(indices.size)
+            self.operator_complexity = (total_nnz + indices.size) / fine_nnz
             metrics.gauge("amg_operator_complexity").set(
                 self.operator_complexity
             )
             with trace.span("amg.coarse_solver", cat="amg",
-                            kind=coarse_solver, rows=A.shape[0]):
+                            kind=coarse_solver, rows=n):
+                self._coarse_inv = None
                 if coarse_solver == "dense":
-                    dense = to_dense(A, executor=executor)
-                    self._coarse_inv = jnp.linalg.inv(
-                        dense.astype(jnp.float32)
-                    ).astype(self._dtype)
-                    self._coarse_solver = None
-                else:
-                    from repro.solvers.common import Stop
-                    from repro.solvers.krylov import CgSolver
-
-                    self._coarse_inv = None
-                    self._coarse_solver = CgSolver(
-                        A,
-                        stop=Stop(max_iters=50, reduction_factor=1e-8),
-                        executor=executor,
+                    if n > DENSE_COARSE_MAX_ROWS:
+                        raise ValueError(
+                            f"the coarsest level has {n} rows, more than the "
+                            f"dense inverse takes ({DENSE_COARSE_MAX_ROWS}); "
+                            "raise coarsening (a lower theta, more levels) or "
+                            "use coarse_solver='cg'"
+                        )
+                    rows = np.repeat(np.arange(n), np.diff(indptr))
+                    dense = np.zeros((n, n))
+                    dense[rows, indices] = values
+                    self._coarse_inv = jnp.asarray(
+                        np.linalg.inv(dense).astype(self._dtype)
                     )
 
     @property
@@ -344,7 +381,7 @@ class Multigrid(LinOp):
 
     def _smooth(self, L: AmgLevel, x, r, sweeps: int, executor):
         for _ in range(sweeps):
-            res = r - sp_apply(L.A_op, x, executor=executor)
+            res = r - sp_apply(L.A, x, executor=executor)
             if L.smoother is not None:
                 x = x + L.smoother.apply(res, executor=executor)
             else:
@@ -353,40 +390,75 @@ class Multigrid(LinOp):
 
     def _coarse_solve(self, r, executor):
         if self._coarse_inv is not None:
-            return self._coarse_inv @ r
-        return self._coarse_solver.apply(r, executor=executor)
+            return jnp.dot(self._coarse_inv, r,
+                           precision=jax.lax.Precision.HIGHEST)
+        from repro.solvers.common import Stop
+        from repro.solvers.krylov import cg
+
+        return cg(self.coarse_A, r,
+                  stop=Stop(max_iters=50, reduction_factor=1e-8),
+                  executor=executor, strict=False).x
 
     def _cycle(self, lvl: int, r, executor):
         if lvl == len(self.levels):
-            return self._coarse_solve(r, executor)
+            with jax.named_scope("Multigrid.coarse"):
+                return self._coarse_solve(r, executor)
         L = self.levels[lvl]
-        x = self._smooth(L, jnp.zeros_like(r), r, self.pre_sweeps, executor)
-        rc = sp_apply(L.R_op, r - sp_apply(L.A_op, x, executor=executor),
-                      executor=executor)
+        with jax.named_scope(f"Multigrid.level{lvl}"):
+            x = self._smooth(L, jnp.zeros_like(r), r, self.pre_sweeps,
+                             executor)
+            res = r - sp_apply(L.A, x, executor=executor)
+        with jax.named_scope(f"Multigrid.restrict{lvl}"):
+            rc = sp_apply(L.R, res, executor=executor)
         xc = self._cycle(lvl + 1, rc, executor)
         if self.cycle == "w" and lvl + 1 < len(self.levels):
             # second recursive visit (γ = 2): correct with the updated
             # coarse residual before interpolating back up (the coarsest
             # visit is exact already — no second solve there)
-            rc2 = rc - sp_apply(
-                self.levels[lvl + 1].A_op, xc, executor=executor
-            )
+            with jax.named_scope(f"Multigrid.level{lvl + 1}"):
+                rc2 = rc - sp_apply(
+                    self.levels[lvl + 1].A, xc, executor=executor
+                )
             xc = xc + self._cycle(lvl + 1, rc2, executor)
-        x = x + sp_apply(L.P_op, xc, executor=executor)
-        return self._smooth(L, x, r, self.post_sweeps, executor)
+        with jax.named_scope(f"Multigrid.prolong{lvl}"):
+            x = x + sp_apply(L.P, xc, executor=executor)
+        with jax.named_scope(f"Multigrid.level{lvl}"):
+            return self._smooth(L, x, r, self.post_sweeps, executor)
 
     def _apply(self, r: jax.Array, executor) -> jax.Array:
         ex = executor if executor is not None else self.executor
-        if not self.levels:
-            return self._coarse_solve(r, ex)
         return self._cycle(0, r, ex)
 
 
-def amg_preconditioner(A: Csr, *, executor=None, **opts) -> Multigrid:
-    """``M="amg"`` factory — one V(1,1)-cycle of smoothed aggregation."""
-    if not isinstance(A, Csr):
+def _multigrid_flatten(mg: Multigrid):
+    children = (mg.levels, mg.coarse_A, mg._coarse_inv)
+    meta = (mg._shape, mg._dtype, mg.cycle, mg.omega, mg.pre_sweeps,
+            mg.post_sweeps, mg.operator_complexity, mg.executor)
+    return children, meta
+
+
+def _multigrid_unflatten(meta, children) -> Multigrid:
+    mg = object.__new__(Multigrid)
+    mg.levels, mg.coarse_A, mg._coarse_inv = children
+    (mg._shape, mg._dtype, mg.cycle, mg.omega, mg.pre_sweeps,
+     mg.post_sweeps, mg.operator_complexity, mg.executor) = meta
+    return mg
+
+
+# a pytree (arrays as data, level count and shapes as meta), so one compile
+# of a jitted solve serves every request that hands it the same hierarchy
+jax.tree_util.register_pytree_node(
+    Multigrid, _multigrid_flatten, _multigrid_unflatten
+)
+
+
+def amg_preconditioner(A, *, executor=None, **opts) -> Multigrid:
+    """``M="amg"`` factory — one V(1,1)-cycle of smoothed aggregation on a
+    CSR or ELL operand."""
+    if not isinstance(A, (Csr, Ell)):
         raise TypeError(
-            f"amg preconditioner needs a CSR operand, got {type(A).__name__}"
+            f"amg preconditioner needs a CSR or ELL operand, got "
+            f"{type(A).__name__}"
         )
     return Multigrid(A, executor=executor, **opts)
 

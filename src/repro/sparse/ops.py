@@ -513,8 +513,9 @@ def norm2(x, *, executor=None):
 #   1. row-nnz upper-bound pass — expand each a_ik into the length of B's row
 #      k (the classical "symbolic" upper bound, before duplicate merging);
 #   2. numeric expansion — produce the (row, col, a_ik·b_kj) triplets (this is
-#      the flop-carrying pass; the pallas space runs it as a tiled kernel in
-#      ``repro.kernels.spgemm``);
+#      the flop-carrying pass: a sequential merge in the reference space, a
+#      device gather-multiply in the xla space, which the pallas executor
+#      falls back to as well);
 #   3. coalesce — sort triplets by (row, col), merge duplicates, build indptr.
 #
 # All three spaces share steps 1 and 3 bit-for-bit, so the output *structure*
@@ -542,19 +543,15 @@ def _empty_csr(m: int, n: int, dtype) -> Csr:
 def _spgemm_maps(A: Csr, B: Csr):
     """Host structure pass: expansion maps for C = A·B.
 
-    Returns ``(rows_a, b_start, b_len, K)`` where entry t of A contributes
-    products against ``b_len[t]`` entries of B starting at ``b_start[t]``,
-    lands in output row ``rows_a[t]``, and ``K`` is the padded expansion
-    width (max B-row nnz reached by A's column indices).
+    Returns ``(rows_a, b_start, b_len)`` where entry t of A contributes
+    products against ``b_len[t]`` entries of B starting at ``b_start[t]``
+    and lands in output row ``rows_a[t]``.
     """
     ai = np.asarray(A.indptr)
     ac = np.asarray(A.indices)
     bi = np.asarray(B.indptr)
     rows_a = np.repeat(np.arange(A.shape[0], dtype=np.int64), np.diff(ai))
-    b_start = bi[ac]
-    b_len = np.diff(bi)[ac]
-    K = int(b_len.max()) if b_len.size else 0
-    return rows_a, b_start, b_len, K
+    return rows_a, bi[ac], np.diff(bi)[ac]
 
 
 def _coalesce_host(rows, cols, vals, m: int):
@@ -580,16 +577,6 @@ def _coalesce_host(rows, cols, vals, m: int):
     indptr = np.zeros(m + 1, np.int64)
     indptr[1:] = np.cumsum(np.bincount(out_r, minlength=m))
     return indptr, out_c.astype(np.int32), out_v
-
-
-def _finalize_spgemm(rows_a, K, valid, cols, prod, m, n) -> Csr:
-    """Pull the expanded (possibly padded) triplets to host and coalesce."""
-    vmask = np.asarray(valid).ravel()
-    rows_f = np.repeat(rows_a, K)[vmask]
-    cols_f = np.asarray(cols).ravel()[vmask]
-    vals_f = np.asarray(prod).ravel()[vmask]
-    indptr, out_c, out_v = _coalesce_host(rows_f, cols_f, vals_f, m)
-    return csr_from_arrays(indptr, out_c, out_v, (m, n))
 
 
 @spgemm_op.register("reference")
@@ -633,21 +620,40 @@ def _spgemm_ref(ex, A: Csr, B: Csr) -> Csr:
 
 @spgemm_op.register("xla")
 def _spgemm_xla(ex, A: Csr, B: Csr) -> Csr:
-    """One-shot expansion: gather B's rows padded to width K, multiply on
-    device, coalesce on host.  The device pass is a single fused
-    gather-multiply the compiler vectorizes; K is the max B-row width so the
-    expansion is rectangular (the predication-free padding idiom)."""
+    """Every product ``a_ik·b_kj`` as a device gather-multiply over exactly
+    the expanded entries (at most :data:`_EXPAND_CHUNK` at a time),
+    coalesced on the host."""
     m, _ = A.shape
     n = B.shape[1]
-    rows_a, b_start, b_len, K = _spgemm_maps(A, B)
-    if K == 0 or rows_a.size == 0:
+    rows_a, b_start, b_len = _spgemm_maps(A, B)
+    total = int(b_len.sum())
+    if total == 0:
         return _empty_csr(m, n, np.result_type(A.dtype, B.dtype))
-    q = np.arange(K)
-    valid = q[None, :] < b_len[:, None]  # (nnzA, K) host bool
-    idx = jnp.asarray(np.where(valid, b_start[:, None] + q[None, :], 0))
-    prod = A.values[:, None] * B.values[idx]
-    cols = B.indices[idx]
-    return _finalize_spgemm(rows_a, K, valid, cols, prod, m, n)
+    # product p pairs entry t[p] of A with entry idx[p] of B, in row order
+    t = np.repeat(np.arange(b_len.size, dtype=np.int32), b_len)
+    idx = np.arange(total, dtype=np.int64) - np.repeat(
+        np.cumsum(b_len) - b_len - b_start, b_len)
+    idx32 = idx.astype(np.int32)
+    # in chunks of one padded shape, so the device holds one chunk's maps
+    step = min(total, _EXPAND_CHUNK)
+    prod = np.concatenate([
+        np.asarray(_expand(A.values, B.values,
+                           np.resize(t[lo:lo + step], step),
+                           np.resize(idx32[lo:lo + step], step)))
+        for lo in range(0, total, step)])[:total]
+    cols = np.asarray(B.indices)[idx]
+    rows = np.repeat(rows_a, b_len)
+    indptr, out_c, out_v = _coalesce_host(rows, cols, prod, m)
+    return csr_from_arrays(indptr, out_c, out_v, (m, n))
+
+
+#: products a device expansion computes at once
+_EXPAND_CHUNK = 1 << 24
+
+
+@jax.jit
+def _expand(a_vals, b_vals, t, idx):
+    return a_vals[t] * b_vals[idx]
 
 
 @sptranspose_op.register("reference")
@@ -668,9 +674,14 @@ def _sptranspose_ref(ex, A: Csr) -> Csr:
 
 @sptranspose_op.register("xla")
 def _sptranspose_xla(ex, A: Csr) -> Csr:
+    return _sptranspose_device(A)
+
+
+@jax.jit
+def _sptranspose_device(A: Csr) -> Csr:
     """Device transpose: nnz is invariant so every array keeps a static
-    shape — the whole permutation (lexsort + bincount) stays on device and
-    is jit-traceable."""
+    shape — the whole permutation (lexsort + bincount) stays on device, in
+    one program."""
     m, n = A.shape
     rows = _csr_row_ids(A)
     order = jnp.lexsort((rows, A.indices))

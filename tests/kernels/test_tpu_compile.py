@@ -158,3 +158,38 @@ def test_fused_cg_block_jacobi_compiles(one_chip, ex):
     assert "while" in text
     # the fused loop: spmv_dot_ell, axpy_norm and block_jacobi_apply kernels
     assert text.count("tpu_custom_call") >= 3
+
+
+def test_fused_cg_amg_compiles(one_chip, ex):
+    """CG with one smoothed-aggregation V-cycle as ``M``, the hierarchy a
+    jit argument: the band SpMV on the fine level, the rectangular packed
+    ``spmv_ell`` of every transfer and the packed coarse operators inside the
+    loop.  The hierarchy is built here on the CPU at 32³ and handed over as
+    shapes."""
+    import numpy as np
+
+    from repro import sparse
+    from repro.precond import make_preconditioner
+    from repro.solvers import krylov
+    from repro.solvers.common import Stop
+    from repro.sparse.gallery import poisson_3d
+
+    indptr, indices, values, shape = poisson_3d(32)
+    A = sparse.ell_from_csr_host(indptr, indices, values.astype(np.float32), shape)
+    M_ = make_preconditioner(A, "amg", executor=ex, theta=0.0)
+    assert M_.num_levels >= 3
+    assert any(L.R.offsets is None and L.R.shape[0] < L.R.shape[1] for L in M_.levels)
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+
+    def solve(A, M, b):
+        res = krylov.cg(A, b, M=M, executor=ex, strict=False,
+                        stop=Stop(max_iters=1000, reduction_factor=1e-6))
+        return res.x, res.iterations
+
+    text = _compile(solve, shapes(A), shapes(M_), _sds(one_chip, (shape[0],)))
+    assert "while" in text
+    # the fine level's band kernels and the transfers' packed kernel
+    assert "spmv_dot_ell_band" in text and "spmv_ell_band" in text
+    assert text.count("tpu_custom_call") >= 2 * M_.num_levels

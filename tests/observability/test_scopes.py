@@ -154,3 +154,31 @@ def test_conversion_and_block_jacobi_generation_record_their_steps():
         ev = events[s]
         assert parent["ts"] <= ev["ts"]
         assert ev["ts"] + ev["dur"] <= parent["ts"] + parent["dur"]
+
+
+def test_the_multigrid_cycle_names_its_levels_transfers_and_coarse_solve():
+    """Inside ``Multigrid.apply`` every level's smoothing and residual, each
+    restriction and prolongation, and the coarse solve carry scopes of their
+    own; setup counts each level's transfer entries."""
+    from repro.observability import metrics
+
+    ex = make_executor("xla")
+    A = _poisson_ell(12)
+    metrics.reset()
+    M = make_preconditioner(A, "amg", executor=ex, theta=0.0, coarse_size=16)
+    assert M.num_levels >= 3
+    ops = [op for *_, op in _instructions(_cg_hlo(A, M, ex)) if op]
+    parts = [set(op.split("/")) for op in ops]
+    names = ["Multigrid.coarse"]
+    for k in range(M.num_levels - 1):
+        names += [f"Multigrid.level{k}", f"Multigrid.restrict{k}", f"Multigrid.prolong{k}"]
+    for name in names:
+        assert any({"while", "Multigrid.apply", name} <= p for p in parts), name
+    # the scopes of one level never nest in another's
+    assert not any({"Multigrid.level0", "Multigrid.level1"} <= p for p in parts)
+    gauges = {(s["name"], s["labels"].get("level")): s["value"] for s in metrics.samples()}
+    for k, L in enumerate(M.levels):
+        stored = int(np.count_nonzero(np.asarray(L.P.values))
+                     + np.count_nonzero(np.asarray(L.R.values)))
+        assert gauges["amg_transfer_nnz", str(k)] == stored
+    assert ("amg_transfer_nnz", str(M.num_levels - 1)) not in gauges

@@ -267,3 +267,96 @@ def test_batch_amg_apply_rows_independent():
     np.testing.assert_allclose(
         np.asarray(half[0]), 0.5 * np.asarray(solo0[0]), atol=1e-5, rtol=1e-5
     )
+
+
+# =============================================================================
+# 3-D hierarchies, ELL operands, the pytree
+# =============================================================================
+
+
+def _poisson3d(n_side):
+    from repro.sparse.gallery import poisson_3d
+
+    indptr, indices, values, shape = poisson_3d(n_side)
+    return indptr, indices, values.astype(np.float32), shape
+
+
+@pytest.mark.parametrize("n_side", [16, 32])
+def test_3d_hierarchy_at_theta_0_coarsens_twofold(n_side):
+    A = csr_from_arrays(*_poisson3d(n_side))
+    M = amg_preconditioner(A, theta=0.0)
+    rows = [L.A.shape[0] for L in M.levels] + [M.coarse_A.shape[0]]
+    assert len(rows) >= 3 and rows[-1] <= 64
+    assert all(2 * b <= a for a, b in zip(rows, rows[1:])), rows
+    assert M.operator_complexity < 2.0
+
+
+def test_a_level_whose_aggregation_keeps_most_rows_ends_the_descent():
+    """At θ = 0.08 the second level of the 3-D stencil's hierarchy would
+    keep all but one of its rows: it becomes the coarsest level."""
+    A = csr_from_arrays(*_poisson3d(32))
+    M = amg_preconditioner(A)
+    assert M.num_levels == 2
+    n_c = M.coarse_A.shape[0]
+    assert n_c < A.shape[0] // 2
+    indptr, indices, values = sparse.csr_host_arrays(M.coarse_A)
+    _, n_agg = aggregate(indptr, indices, strength_mask(indptr, indices, values),
+                         n_c)
+    assert 2 * n_agg > n_c
+
+
+def test_a_coarsest_level_too_large_for_the_dense_inverse_is_refused(monkeypatch):
+    from repro.precond import amg
+
+    monkeypatch.setattr(amg, "DENSE_COARSE_MAX_ROWS", 20)
+    A = _poisson(12)
+    with pytest.raises(ValueError, match=r"coarsest level has \d+ rows"):
+        amg_preconditioner(A, coarse_size=40)
+    # the CG coarse solver takes it
+    M = amg_preconditioner(A, coarse_size=40, coarse_solver="cg")
+    assert M.coarse_A.shape[0] > 20
+
+
+def test_an_ell_operand_gives_the_levels_and_cycle_of_the_equal_csr():
+    indptr, indices, values, shape = _poisson3d(16)
+    ell = sparse.ell_from_csr_host(indptr, indices, values, shape)
+    assert ell.offsets is not None  # the band layout
+    csr = csr_from_arrays(indptr, indices, values, shape)
+    M_ell = make_preconditioner(ell, "amg", theta=0.0)
+    M_csr = make_preconditioner(csr, "amg", theta=0.0)
+    assert M_ell.levels[0].A is ell  # the fine level is the operand itself
+    # a CSR operand is converted to the same ELL once, for the cycle's SpMVs
+    fine = M_csr.levels[0].A
+    assert isinstance(fine, sparse.Ell) and fine.offsets == ell.offsets
+    np.testing.assert_array_equal(np.asarray(fine.values), np.asarray(ell.values))
+    assert M_ell.num_levels == M_csr.num_levels
+    for a, b in zip(M_ell.levels, M_csr.levels):
+        for name in ("P", "R"):
+            np.testing.assert_array_equal(np.asarray(getattr(a, name).col_idx),
+                                          np.asarray(getattr(b, name).col_idx))
+            np.testing.assert_allclose(np.asarray(getattr(a, name).values),
+                                       np.asarray(getattr(b, name).values),
+                                       rtol=1e-6, atol=1e-7)
+    r = jnp.asarray(np.random.default_rng(7).normal(size=shape[0]), jnp.float32)
+    np.testing.assert_allclose(np.asarray(M_ell.apply(r)), np.asarray(M_csr.apply(r)),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_multigrid_is_a_pytree_that_a_jitted_solve_takes():
+    indptr, indices, values, shape = _poisson3d(16)
+    A = sparse.ell_from_csr_host(indptr, indices, values, shape)
+    M = make_preconditioner(A, "amg", theta=0.0)
+    leaves, tree = jax.tree_util.tree_flatten(M)
+    assert all(isinstance(leaf, jax.Array) for leaf in leaves)
+    again = jax.tree_util.tree_unflatten(tree, leaves)
+    assert again.num_levels == M.num_levels and again.cycle == M.cycle
+    stop = Stop(max_iters=100, reduction_factor=1e-6)
+    solve = jax.jit(lambda A, M, b: cg(A, b, M=M, stop=stop, strict=False))
+    rng = np.random.default_rng(8)
+    b = jnp.asarray(rng.normal(size=shape[0]), jnp.float32)
+    res = solve(A, M, b)
+    assert bool(res.converged) and int(res.iterations) <= 15
+    solve(A, M, 2 * b)
+    assert solve._cache_size() == 1  # one compile serves every right-hand side
+    r = b - sparse.apply(A, res.x)
+    assert float(jnp.linalg.norm(r)) <= 2e-6 * float(jnp.linalg.norm(b))

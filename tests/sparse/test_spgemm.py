@@ -186,3 +186,21 @@ def test_spgemm_structure_identical_across_executors(n, density, seed):
             np.asarray(got.values), np.asarray(ref.values),
             atol=1e-4, rtol=1e-4,
         )
+
+
+def test_spgemm_in_chunks_matches_the_reference(monkeypatch):
+    """The xla space expands a product in chunks of one padded shape; the
+    last chunk's padding contributes nothing."""
+    from repro.sparse import ops
+
+    a = _rand_sparse(13, 11, 0.4, 21)
+    b = _rand_sparse(11, 9, 0.4, 22)
+    A, B = csr_from_dense(a), csr_from_dense(b)
+    ref = spgemm(A, B, executor=make_executor("reference"))
+    monkeypatch.setattr(ops, "_EXPAND_CHUNK", 7)
+    got = spgemm(A, B, executor=make_executor("xla"))
+    np.testing.assert_array_equal(np.asarray(got.indptr), np.asarray(ref.indptr))
+    np.testing.assert_array_equal(np.asarray(got.indices), np.asarray(ref.indices))
+    np.testing.assert_allclose(np.asarray(got.values), np.asarray(ref.values),
+                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(_dense(got), a @ b, atol=1e-4, rtol=1e-4)
