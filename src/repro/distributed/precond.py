@@ -79,11 +79,12 @@ class DistBlockJacobi(LinOp):
     Each shard applies a plain :class:`repro.precond.BlockJacobi` built from
     its slice of the stacked inverted blocks — the apply dispatches through
     the ``block_jacobi_apply`` kernel family like the single-device path.
+    Every shard's blocks are uniform with one storage class, so block ``b`` of
+    a shard holds its padded rows ``[b*bs, (b+1)*bs)``: the row layout, with
+    no index maps.
     """
 
     inv_blocks: jax.Array  # (P, nb, bs, bs) in the storage dtype
-    gather_idx: jax.Array  # (P, nb, bs) i32
-    scatter_idx: jax.Array  # (P, Lmax) i32
     partition: Partition  # static
     block_size: int  # static
 
@@ -107,8 +108,8 @@ class DistBlockJacobi(LinOp):
 
         return BlockJacobi(
             inv_blocks=(self.inv_blocks[0],),
-            gather_idx=self.gather_idx[0],
-            scatter_idx=self.scatter_idx[0],
+            gather_idx=None,
+            scatter_idx=None,
             n=self.partition.max_part_size,
             block_size=self.block_size,
             num_blocks=self.inv_blocks.shape[1],
@@ -122,22 +123,20 @@ class DistBlockJacobi(LinOp):
         # applies per shard through the block_jacobi_apply kernel family.)
         part = self.partition
         vp = part.pad(v)  # (P, Lmax)
-        nparts, nb, bs = self.gather_idx.shape
-        vpad = jnp.concatenate(
-            [vp, jnp.zeros((nparts, 1), vp.dtype)], axis=1
-        )  # slot Lmax = the zero-pad slot gather_idx points at
-        g = jnp.take_along_axis(
-            vpad, self.gather_idx.reshape(nparts, nb * bs), axis=1
-        ).reshape(nparts, nb, bs)
+        nparts, nb, bs, _ = self.inv_blocks.shape
+        lmax = vp.shape[1]
+        g = jnp.concatenate(
+            [vp, jnp.zeros((nparts, nb * bs - lmax), vp.dtype)], axis=1
+        ).reshape(nparts, nb, bs)  # zeros under a ragged last block
         y = jnp.einsum(
             "pnij,pnj->pni", self.inv_blocks.astype(vp.dtype), g
         ).reshape(nparts, nb * bs)
-        return part.unpad(jnp.take_along_axis(y, self.scatter_idx, axis=1))
+        return part.unpad(y[:, :lmax])
 
 
 jax.tree_util.register_dataclass(
     DistBlockJacobi,
-    data_fields=["inv_blocks", "gather_idx", "scatter_idx"],
+    data_fields=["inv_blocks"],
     meta_fields=["partition", "block_size"],
 )
 
@@ -192,12 +191,12 @@ def dist_block_jacobi(
         for p in range(A.partition.num_parts)
     ]
     # uniform blocks + uniform (or no) adaptive class => exactly one stacked
-    # precision tensor per part, all the same shape
-    assert all(len(bj.inv_blocks) == 1 for bj in per_part)
+    # precision tensor per part, all the same shape, in the row layout
+    assert all(
+        len(bj.inv_blocks) == 1 and bj.layout == "row" for bj in per_part
+    )
     return DistBlockJacobi(
         inv_blocks=jnp.stack([bj.inv_blocks[0] for bj in per_part]),
-        gather_idx=jnp.stack([bj.gather_idx for bj in per_part]),
-        scatter_idx=jnp.stack([bj.scatter_idx for bj in per_part]),
         partition=A.partition,
         block_size=per_part[0].block_size,
     )

@@ -37,7 +37,7 @@ import numpy as np
 from repro.batch.linop import BatchLinOp
 from repro.core import registry
 from repro.core.linop import LinOp
-from repro.observability import trace
+from repro.observability import metrics, trace
 from repro.sparse.formats import csr_host_arrays
 
 __all__ = [
@@ -289,15 +289,23 @@ class BlockJacobi(LinOp):
     blocks.
 
     ``inv_blocks`` holds one stacked sub-batch per storage precision present
-    (class-ordered, static shapes); ``gather_idx``/``scatter_idx`` are the
-    host-precomputed maps between vector rows and (block, local-row) slots in
-    that class order.  A LinOp — use directly as a solver's ``M`` or inside
-    any operator composition.
+    (class-ordered, static shapes).  Two layouts map vector rows to
+    (block, local-row) slots in that class order:
+
+    * **row** (``gather_idx``/``scatter_idx`` are ``None``): block ``b`` holds
+      rows ``[b*bs, (b+1)*bs)`` — uniform blocks whose class order is the
+      row order.  The apply reshapes the vector, padding a ragged last block.
+    * **gathered**: the host-precomputed maps gather the vector into the
+      slots and scatter the result back — natural or varying blocks, and
+      adaptive storage whose classes interleave.
+
+    A LinOp — use directly as a solver's ``M`` or inside any operator
+    composition.
     """
 
     inv_blocks: Tuple[jax.Array, ...]
-    gather_idx: jax.Array  # (nb, bs) int32; n = zero-pad slot
-    scatter_idx: jax.Array  # (n,) int32 into the flat (nb*bs,) apply output
+    gather_idx: Optional[jax.Array]  # (nb, bs) int32; n = zero-pad slot
+    scatter_idx: Optional[jax.Array]  # (n,) int32 into the flat (nb*bs,) output
     n: int
     block_size: int  # bs (padded/max block size)
     num_blocks: int
@@ -310,6 +318,11 @@ class BlockJacobi(LinOp):
     @property
     def dtype(self):
         return self.inv_blocks[0].dtype if self.inv_blocks else None
+
+    @property
+    def layout(self) -> str:
+        """``"row"`` or ``"gathered"`` (class docstring)."""
+        return "row" if self.gather_idx is None else "gathered"
 
     @property
     def storage_dtypes(self) -> Tuple[str, ...]:
@@ -327,8 +340,14 @@ class BlockJacobi(LinOp):
     def _apply(self, v: jax.Array, executor) -> jax.Array:
         if not self.inv_blocks:  # degenerate 0-row system
             return v
-        vpad = jnp.concatenate([v, jnp.zeros((1,), v.dtype)])
-        vp = vpad[self.gather_idx]  # (nb, bs), class-ordered
+        if self.gather_idx is None:
+            padded = self.num_blocks * self.block_size
+            if padded != self.n:  # zeros under a ragged last block
+                v = jnp.concatenate([v, jnp.zeros((padded - self.n,), v.dtype)])
+            vp = v.reshape(self.num_blocks, self.block_size)
+        else:
+            vpad = jnp.concatenate([v, jnp.zeros((1,), v.dtype)])
+            vp = vpad[self.gather_idx]  # (nb, bs), class-ordered
         outs = []
         off = 0
         for t in self.inv_blocks:
@@ -340,6 +359,8 @@ class BlockJacobi(LinOp):
             )
             off += nbc
         y = jnp.concatenate(outs, axis=0).reshape(-1)
+        if self.scatter_idx is None:
+            return y[: self.n]
         return y[self.scatter_idx]
 
     def transpose(self) -> "BlockJacobi":
@@ -376,8 +397,35 @@ def block_jacobi(
     ``adaptive=True`` turns on per-block storage-precision selection;
     a dtype forces every block into that storage.
     """
-    with trace.span("block_jacobi.generate", cat="precond"):
-        return _generate(A, block_size, blocks, adaptive, tau, executor)
+    with trace.span("block_jacobi.generate", cat="precond") as span:
+        M = _generate(A, block_size, blocks, adaptive, tau, executor)
+        metrics.counter("block_jacobi.layout", layout=M.layout).inc()
+        span.annotate(layout=M.layout)
+        return M
+
+
+def _block_maps(
+    block_ptrs: np.ndarray, order: np.ndarray, bs: int
+) -> Tuple[np.ndarray, np.ndarray]:
+    """The gathered layout's ``(gather, scatter)`` maps.
+
+    Row ``r`` sits at local slot ``r - lo`` of its block, which sits at
+    position ``pos_of[block]`` of the class ``order``: ``gather`` is
+    ``(nb, bs)`` int32 with ``n`` in the zero-pad slots, ``scatter`` is
+    ``(n,)`` int32 into the flat ``(nb*bs,)`` apply output.
+    """
+    n, nb = int(block_ptrs[-1]), len(block_ptrs) - 1
+    sizes = np.diff(block_ptrs)
+    gather = np.full((nb, bs), n, np.int32)
+    scatter = np.zeros(n, np.int32)
+    pos_of = np.empty(nb, np.int64)
+    pos_of[order] = np.arange(nb)
+    r = np.arange(n, dtype=np.int64)
+    blk = np.repeat(np.arange(nb, dtype=np.int64), sizes)
+    slot, pos = r - block_ptrs[blk], pos_of[blk]
+    gather[pos, slot] = r
+    scatter[r] = pos * bs + slot
+    return gather, scatter
 
 
 def _generate(A, block_size, blocks, adaptive, tau, executor) -> BlockJacobi:
@@ -410,19 +458,14 @@ def _generate(A, block_size, blocks, adaptive, tau, executor) -> BlockJacobi:
         class_id = _class_ids(adaptive, blocks_np, inv_np, sizes, tau, base_dtype)
         order = np.argsort(class_id, kind="stable")
 
-    # gather/scatter maps in class order (host-precomputed, device gathers):
-    # row r sits at local slot r - lo of its block, which sits at position
-    # pos_of[block] of the class order
-    with trace.span("block_jacobi.maps", cat="precond"):
-        gather = np.full((nb, bs), n, np.int32)
-        scatter = np.zeros(n, np.int32)
-        pos_of = np.empty(nb, np.int64)
-        pos_of[order] = np.arange(nb)
-        r = np.arange(n, dtype=np.int64)
-        blk = np.repeat(np.arange(nb, dtype=np.int64), sizes)
-        slot, pos = r - block_ptrs[blk], pos_of[blk]
-        gather[pos, slot] = r
-        scatter[r] = pos * bs + slot
+    # the row layout needs no maps: every block but a ragged last one holds
+    # bs rows, and the class order is the row order
+    row = bool((sizes[:-1] == bs).all()) and bool((order == np.arange(nb)).all())
+    gather_idx = scatter_idx = None
+    if not row:
+        with trace.span("block_jacobi.maps", cat="precond"):
+            gather, scatter = _block_maps(block_ptrs, order, bs)
+            gather_idx, scatter_idx = jnp.asarray(gather), jnp.asarray(scatter)
 
     with trace.span("block_jacobi.upload", cat="precond"):
         classes = _storage_classes(base_dtype)
@@ -433,7 +476,6 @@ def _generate(A, block_size, blocks, adaptive, tau, executor) -> BlockJacobi:
             if len(members) == 0:
                 continue
             tensors.append(jnp.asarray(inv_np[members]).astype(dtype))
-        gather_idx, scatter_idx = jnp.asarray(gather), jnp.asarray(scatter)
 
     return BlockJacobi(
         inv_blocks=tuple(tensors),

@@ -13,6 +13,9 @@ worker collects the same tests and only the worker that runs this file loads
 the TPU compiler.
 """
 
+import math
+import re
+
 import pytest
 
 import jax
@@ -67,6 +70,16 @@ def _ell(one_chip, offsets=None):
         shape=(M, M),
         offsets=offsets,
     )
+
+
+def _row_gathers(text):
+    """The compiled program's gathers whose result has ``M`` elements."""
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= [a-z0-9]+\[([0-9,]*)\]\S* gather\(", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",") if d) == M:
+            found.append(line.strip())
+    return found
 
 
 def _compile(fn, *args):
@@ -158,6 +171,39 @@ def test_fused_cg_block_jacobi_compiles(one_chip, ex):
     assert "while" in text
     # the fused loop: spmv_dot_ell, axpy_norm and block_jacobi_apply kernels
     assert text.count("tpu_custom_call") >= 3
+    # the gathered layout moves the vector through its maps, both ways
+    assert len(_row_gathers(text)) >= 2
+
+
+def test_fused_cg_block_jacobi_row_compiles(one_chip, ex):
+    """The fused-CG loop with a row-layout block-Jacobi (uniform blocks, one
+    storage class, no maps): the apply reshapes the vector and the program
+    holds no gather of an ``M``-row vector."""
+    from repro.precond import BlockJacobi
+    from repro.solvers import krylov
+    from repro.solvers.common import Stop
+
+    nb = M // BS
+
+    def solve(A, inv, b):
+        M_ = BlockJacobi(
+            inv_blocks=(inv,), gather_idx=None, scatter_idx=None,
+            n=M, block_size=BS, num_blocks=nb,
+        )
+        assert M_.layout == "row"
+        res = krylov.cg(A, b, M=M_, executor=ex, strict=False,
+                        stop=Stop(max_iters=1000, reduction_factor=1e-6))
+        return res.x, res.iterations
+
+    text = _compile(
+        solve,
+        _ell(one_chip, OFFSETS),
+        _sds(one_chip, (nb, BS, BS)),
+        _sds(one_chip, (M,)),
+    )
+    assert "while" in text
+    assert text.count("tpu_custom_call") >= 3
+    assert _row_gathers(text) == []
 
 
 def test_fused_cg_amg_compiles(one_chip, ex):

@@ -69,15 +69,26 @@ def bj_system():
 def test_fused_cg_gathers_carry_their_op_and_linop_scopes(bj_system):
     A, M, ex = bj_system
     assert sparse.ops.has_fused_ops(A, executor=ex)
-    gathers = [op for _, opcode, op in _instructions(_cg_hlo(A, M, ex))
-               if opcode == "gather"]
-    parts = [set(op.split("/")) for op in gathers]
+
+    def gather_scopes(M):
+        return [set(op.split("/")) for _, opcode, op in
+                _instructions(_cg_hlo(A, M, ex)) if opcode == "gather"]
+
+    parts = gather_scopes(M)
     # the x[col_idx] gather of the loop's fused SpMV + dot
-    assert any({"while", "spmv_dot_ell"} <= p for p in parts), gathers
-    # the block-Jacobi gather and scatter around the apply kernel
-    assert sum("BlockJacobi.apply" in p for p in parts) >= 2, gathers
+    assert any({"while", "spmv_dot_ell"} <= p for p in parts), parts
     # the initial residual's SpMV, through Ell.apply
-    assert any({"Ell.apply", "spmv_ell"} <= p for p in parts), gathers
+    assert any({"Ell.apply", "spmv_ell"} <= p for p in parts), parts
+    # uniform 8-blocks in one storage class: the row layout gathers nothing
+    assert M.layout == "row"
+    assert not any("BlockJacobi.apply" in p for p in parts), parts
+    # blocks of varying size: the gather and scatter around the apply kernel
+    n = A.shape[0]
+    varied = make_preconditioner(A, "block_jacobi", executor=ex,
+                                 blocks=[0, *range(5, n, 8), n])
+    assert varied.layout == "gathered"
+    parts = gather_scopes(varied)
+    assert sum("BlockJacobi.apply" in p for p in parts) >= 2, parts
 
 
 def test_scopes_leave_the_compiled_program_unchanged(bj_system, monkeypatch):
@@ -144,10 +155,12 @@ def test_conversion_and_block_jacobi_generation_record_their_steps():
     A = _poisson_ell(4)
     make_preconditioner(A, "block_jacobi", executor=make_executor("xla"), block_size=8)
     events = {ev["name"]: ev for ev in tracer.events if ev["cat"] != "dispatch"}
+    # uniform blocks in one storage class: the row layout builds no maps
     steps = ["block_jacobi." + s for s in
-             ("extract", "invert", "classify", "maps", "upload")]
+             ("extract", "invert", "classify", "upload")]
     assert set(events) == {"sparse.ell_from_csr_host", "block_jacobi.generate", *steps}
     parent = events["block_jacobi.generate"]
+    assert parent["args"]["layout"] == "row"
     starts = [events[s]["ts"] for s in steps]
     assert starts == sorted(starts)  # in order, nested in the parent by time
     for s in steps:

@@ -416,3 +416,125 @@ def test_host_block_extraction_matches_row_loop():
         assert gather.reshape(-1)[scatter[r]] == r
     assert (gather == n).sum() == gather.size - n  # the rest are pad slots
     assert scatter.max() < M.num_blocks * bs
+
+
+# -----------------------------------------------------------------------------
+# layouts: row order when the blocks are uniform and share one storage class
+# -----------------------------------------------------------------------------
+
+
+def _varied_blocks(sizes=(3, 5, 2, 6, 4, 5, 1, 6), seed=4):
+    """Block-diagonal SPD matrix whose natural blocks vary in size."""
+    rng = np.random.default_rng(seed)
+    n = sum(sizes)
+    a = np.zeros((n, n), np.float32)
+    lo = 0
+    for s in sizes:
+        blk = rng.normal(size=(s, s)).astype(np.float32)
+        a[lo : lo + s, lo : lo + s] = blk @ blk.T + 4 * np.eye(s, dtype=np.float32)
+        lo += s
+    return a
+
+
+def _layout_counts():
+    from repro.observability import metrics
+
+    return {
+        layout: metrics.counter("block_jacobi.layout", layout=layout).value
+        for layout in ("row", "gathered")
+    }
+
+
+@pytest.mark.parametrize("transpose", [False, True], ids=["apply", "transpose"])
+@pytest.mark.parametrize("n", [64, 61], ids=["whole", "ragged"])
+@pytest.mark.parametrize("ex_cls", [XlaExecutor, PallasInterpretExecutor],
+                         ids=["xla", "pallas"])
+def test_row_layout_equals_gathered_bitwise(ex_cls, n, transpose):
+    """The row layout carries no maps and applies bitwise as the gathered
+    layout does with identity maps over the same inverted blocks."""
+    import jax
+
+    from repro.precond.block_jacobi import _block_maps
+
+    bs = 8
+    A = sparse.csr_from_dense(block_spd(64, bs, coupling=0.1)[:n, :n])
+    M = block_jacobi(A, block_size=bs, executor=ex_cls())
+    assert M.layout == "row"
+    assert M.gather_idx is None and M.scatter_idx is None
+    gather, scatter = _block_maps(uniform_block_ptrs(n, bs), np.arange(M.num_blocks), bs)
+    G = dataclasses.replace(
+        M, gather_idx=jnp.asarray(gather), scatter_idx=jnp.asarray(scatter)
+    )
+    assert G.layout == "gathered"
+    if transpose:
+        M, G = M.transpose(), G.transpose()
+        assert M.layout == "row" and M.gather_idx is None
+    v = jnp.asarray(np.random.default_rng(5).normal(size=n).astype(np.float32))
+    apply = jax.jit(lambda op, u: op(u))
+    got, want = np.asarray(apply(M, v)), np.asarray(apply(G, v))
+    np.testing.assert_array_equal(got, want)
+    assert got.shape == (n,)
+
+
+@pytest.mark.parametrize(
+    "case, layout",
+    [
+        ("uniform", "row"),
+        ("ragged_uniform", "row"),
+        ("forced_storage", "row"),
+        ("natural_varied", "gathered"),
+        ("explicit_varied", "gathered"),
+        ("adaptive_mixed", "gathered"),
+    ],
+)
+def test_layout_decided_at_generation(case, layout):
+    """Uniform blocks in one storage class take the row layout; varying
+    blocks and interleaved storage classes keep the maps.  Each generation
+    counts its layout once and names it on the ``block_jacobi.generate``
+    span."""
+    from repro.observability import trace
+
+    ex = XlaExecutor()
+    if case in ("natural_varied", "explicit_varied"):
+        a = _varied_blocks()
+        A = sparse.csr_from_dense(a)
+        ptrs = (natural_blocks(A) if case == "natural_varied"
+                else np.array([0, 4, 8, 10, 16, 20, 26, 32]))
+        kwargs = dict(blocks=ptrs)
+    else:
+        a, bs, _, _ = _bench_fixture()
+        if case == "ragged_uniform":
+            a = a[:123, :123]
+        A = sparse.csr_from_dense(a)
+        kwargs = dict(block_size=bs, adaptive={
+            "uniform": False, "ragged_uniform": False,
+            "forced_storage": "bfloat16", "adaptive_mixed": True}[case])
+    before = _layout_counts()
+    tracer = trace.enable()
+    try:
+        M = block_jacobi(A, executor=ex, **kwargs)
+    finally:
+        trace.disable()
+    after = _layout_counts()
+    assert M.layout == layout
+    assert {k: after[k] - before[k] for k in after} == {
+        k: int(k == layout) for k in after
+    }
+    spans = [e for e in tracer.events if e["name"] == "block_jacobi.generate"]
+    assert spans[-1]["args"]["layout"] == layout
+    steps = {e["name"] for e in tracer.events}
+    assert ("block_jacobi.maps" in steps) == (layout == "gathered")
+    if layout == "gathered":
+        assert M.gather_idx.shape == (M.num_blocks, M.block_size)
+        assert M.scatter_idx.shape == (a.shape[0],)
+    if case == "adaptive_mixed":
+        assert len(M.inv_blocks) > 1
+    # either layout applies the inverse of its diagonal blocks
+    v = np.random.default_rng(6).normal(size=a.shape[0]).astype(np.float32)
+    ptrs = kwargs.get("blocks", uniform_block_ptrs(a.shape[0], M.block_size))
+    want = np.concatenate([
+        np.linalg.solve(a[lo:hi, lo:hi], v[lo:hi])
+        for lo, hi in zip(ptrs[:-1], ptrs[1:])
+    ])
+    tol = 2e-2 if case in ("forced_storage", "adaptive_mixed") else 1e-4  # 16-bit storage
+    np.testing.assert_allclose(np.asarray(M(jnp.asarray(v))), want, rtol=tol, atol=tol)
