@@ -13,7 +13,7 @@ Public surface:
 * :mod:`repro.core.coop` — cooperative groups on TPU lane tiles.
 * :mod:`repro.core.params` — per-target hardware parameter tables.
 * :mod:`repro.core.tuning` — launch-configuration resolution (per-target
-  tuning tables + autotune cache) behind ``Executor.launch_config``.
+  tuning tables seeded from ``params``) behind ``Executor.launch_config``.
 """
 
 from repro.core.linop import (

@@ -140,8 +140,8 @@ class Executor:
     # -- launch configuration (paper: per-architecture kernel parameters) --------
     def launch_config(self, op_name: str, shapes: Dict[str, int]):
         """Resolve the tile geometry for ``op_name`` at ``shapes`` on this
-        executor's hardware target (autotune cache -> tuning table ->
-        HardwareParams seed, VMEM-budget checked)."""
+        executor's hardware target (table override, else HardwareParams
+        seed, VMEM-budget checked)."""
         from repro.core import tuning
 
         cfg = tuning.resolve(op_name, shapes, self.hw)

@@ -11,7 +11,7 @@ never double-counts (the padded-shard bug this module's tests pin).
 
 These are the same reduction semantics the distributed solvers get from
 ``repro.sparse.ops.distributed_blas``; the module-level functions here are
-the standalone surface (parity tests, drivers, benchmarks).
+the standalone surface (parity tests, drivers).
 """
 
 from __future__ import annotations

@@ -32,10 +32,6 @@ AXPY_NORM_SPEC = tuning.register_spec(
         ),
         constrain=_constrain,
         floors={"block_n": 1024},
-        candidates=lambda hw, shapes: [
-            {"block_n": hw.lane_count * hw.sublane_count * f}
-            for f in (8, 32, 128)
-        ],
     )
 )
 

@@ -8,7 +8,7 @@ Three kernel spaces:
 * ``pallas``    — the hardware-native tile kernel (:mod:`.kernel`), its block
   batch tile resolved through ``Executor.launch_config`` with the registered
   ``block_jacobi`` :class:`~repro.core.tuning.TuningSpec` — no hard-coded
-  geometry, per-target entries ride the same autotune cache / table override /
+  geometry, per-target entries ride the same table override /
   HardwareParams-seed chain as every other kernel family.
 """
 
@@ -37,9 +37,6 @@ BLOCK_JACOBI_SPEC = tuning.register_spec(
         ),
         constrain=_constrain,
         floors={"block_nb": 1024},
-        candidates=lambda hw, shapes: [
-            {"block_nb": hw.sublane_count * hw.lane_count * f} for f in (1, 4, 16)
-        ],
     )
 )
 

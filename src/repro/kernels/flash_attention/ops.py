@@ -35,16 +35,6 @@ def _constrain(hw, shapes, block):
     }
 
 
-def _candidates(hw, shapes):
-    base = max(hw.mxu_dim, 128)
-    return [
-        {"block_q": base // 2, "block_kv": base // 2},
-        {"block_q": base, "block_kv": base},
-        {"block_q": base, "block_kv": 2 * base},
-        {"block_q": 2 * base, "block_kv": 2 * base},
-    ]
-
-
 ATTENTION_SPEC = tuning.register_spec(
     tuning.TuningSpec(
         op="nn_attention",
@@ -56,7 +46,6 @@ ATTENTION_SPEC = tuning.register_spec(
         vmem_bytes=_vmem_bytes,
         constrain=_constrain,
         floors={"block_q": 8, "block_kv": 8},
-        candidates=_candidates,
     )
 )
 
@@ -79,7 +68,6 @@ CHUNKED_ATTENTION_SPEC = tuning.register_spec(
             )
         },
         floors={"chunk": 128},
-        candidates=lambda hw, shapes: [{"chunk": c} for c in (256, 512, 1024)],
     )
 )
 

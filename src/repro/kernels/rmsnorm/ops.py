@@ -34,9 +34,6 @@ RMSNORM_SPEC = tuning.register_spec(
         vmem_bytes=_vmem_bytes,
         constrain=_constrain,
         floors={"block_rows": 8},
-        candidates=lambda hw, shapes: [
-            {"block_rows": hw.sublane_count * m} for m in (8, 16, 32, 64, 128)
-        ],
     )
 )
 

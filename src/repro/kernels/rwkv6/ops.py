@@ -36,7 +36,6 @@ RWKV6_SPEC = tuning.register_spec(
         vmem_bytes=_vmem_bytes,
         constrain=_constrain,
         floors={"chunk": 8},
-        candidates=lambda hw, shapes: [{"chunk": c} for c in (16, 32, 64)],
     )
 )
 

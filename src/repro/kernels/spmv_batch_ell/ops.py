@@ -3,8 +3,8 @@
 The reference/xla spaces live in :mod:`repro.batch.ops`; this module binds the
 hardware-native skeleton.  Tile geometry resolves through the executor's
 launch-configuration table (new per-target ``spmv_batch_ell`` entries ride the
-same autotune cache / table override / HardwareParams-seed chain as every
-other kernel family — no hard-coded block sizes).
+same table override / HardwareParams-seed chain as every other kernel family
+— no hard-coded block sizes).
 """
 
 from __future__ import annotations
@@ -48,15 +48,6 @@ BATCH_ELL_SPEC = tuning.register_spec(
         vmem_bytes=_vmem_bytes,
         constrain=_constrain,
         floors={"block_m": 8, "block_k": 8},
-        candidates=lambda hw, shapes: [
-            {"block_m": bm, "block_k": bk}
-            for bm in (
-                hw.sublane_count * 8,
-                hw.sublane_count * 16,
-                hw.sublane_count * 32,
-            )
-            for bk in (hw.lane_count // 2, hw.lane_count)
-        ],
     )
 )
 

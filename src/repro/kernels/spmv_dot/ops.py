@@ -19,7 +19,6 @@ from __future__ import annotations
 from repro.core import registry, tuning
 from repro.kernels.spmv_dot.kernel import spmv_dot_ell as spmv_dot_ell_pallas
 from repro.kernels.spmv_ell.ops import (
-    ell_candidates,
     ell_constrain,
     ell_seed,
     ell_vmem_bytes,
@@ -34,7 +33,6 @@ SPMV_DOT_SPEC = tuning.register_spec(
         vmem_bytes=lambda shapes, block: ell_vmem_bytes(shapes, block, dot=True),
         constrain=ell_constrain,
         floors={"block_m": 1024, "block_k": 1},
-        candidates=ell_candidates,
     )
 )
 

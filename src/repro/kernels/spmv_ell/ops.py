@@ -36,15 +36,6 @@ def ell_vmem_bytes(shapes, block, *, dot: bool) -> int:
     )
 
 
-def ell_candidates(hw, shapes):
-    tile = hw.sublane_count * hw.lane_count
-    return [
-        {"block_m": tile * f, "block_k": bk}
-        for f in (4, 8, 32)
-        for bk in (hw.sublane_count, hw.sublane_count * 4)
-    ]
-
-
 ELL_SPEC = tuning.register_spec(
     tuning.TuningSpec(
         op="spmv_ell",
@@ -53,7 +44,6 @@ ELL_SPEC = tuning.register_spec(
         vmem_bytes=lambda shapes, block: ell_vmem_bytes(shapes, block, dot=False),
         constrain=ell_constrain,
         floors={"block_m": 1024, "block_k": 1},
-        candidates=ell_candidates,
     )
 )
 

@@ -39,11 +39,6 @@ SELLP_SPEC = tuning.register_spec(
         vmem_bytes=_vmem_bytes,
         constrain=_constrain,
         floors={"block_cols": 1},
-        candidates=lambda hw, shapes: [
-            {"block_cols": c}
-            for c in (hw.sublane_count // 2, hw.sublane_count, hw.sublane_count * 2)
-            if c >= 1
-        ],
     )
 )
 

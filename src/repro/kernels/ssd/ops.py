@@ -32,7 +32,6 @@ SSD_SPEC = tuning.register_spec(
         vmem_bytes=_vmem_bytes,
         constrain=_constrain,
         floors={"chunk": 8},
-        candidates=lambda hw, shapes: [{"chunk": c} for c in (32, 64, 128)],
     )
 )
 

@@ -9,8 +9,7 @@ complexity) and both convergence histories, then ends with a greppable
 
 * both solves converged,
 * the AMG hierarchy actually coarsened (more than one level), and
-* AMG cut CG iterations by at least ``--iter-cut`` (default 3x; the full
-  10^5-row benchmark in ``benchmarks/report.py`` pins the 5x headline).
+* AMG cut CG iterations by at least ``--iter-cut`` (default 3x).
 
 Usage:
     python -m repro.launch.amg_check --smoke

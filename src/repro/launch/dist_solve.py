@@ -47,7 +47,7 @@ def csr_matvec_f64(host_csr, x: np.ndarray) -> np.ndarray:
 
 
 def build_system(n_side: int, *, nonsym: bool = False, seed: int = 0):
-    """The gallery system the solve drivers and the chip smoke test share.
+    """The gallery system the solve drivers and their tests share.
 
     SPD: the 7-point 3-D Poisson stencil on an ``n_side``³ grid (HPCG's
     problem class; ``n_side=128`` is 2,097,152 rows).  ``nonsym``: upwind

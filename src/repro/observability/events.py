@@ -1,7 +1,7 @@
 """Structured dispatch events — the record behind ``Executor.dispatch_log``.
 
 PR-6 and earlier kept a bare ``Counter`` of op names on each executor.  That
-counter is load-bearing (launch-count pins in ``BENCH_pr*.json``, portability
+counter is load-bearing (launch-count pins in the convergence and portability
 tests), so it stays — but it is now a *derived view*: :class:`DispatchLog`
 subclasses ``Counter`` and additionally keeps a bounded deque of
 :class:`DispatchEvent` records when tracing is enabled.  Each event captures
@@ -10,8 +10,8 @@ what Ginkgo's operation logger sees at a kernel launch:
 * which operation ran, and which **kernel space** served it
   (``reference`` / ``xla`` / ``pallas``);
 * the executor and hardware **target** it ran on;
-* operand **shapes** and a power-of-two **shape bucket** (the same bucketing
-  the tuning tables key on);
+* operand **shapes** and a power-of-two **shape bucket** of the largest
+  operand;
 * the resolved :class:`~repro.core.tuning.LaunchConfig`, when the kernel
   consulted one;
 * **wall time** of the dispatch (trace-time under ``jit`` — structure, not
@@ -53,8 +53,7 @@ def _next_pow2(n: int) -> int:
 def shape_bucket(shapes) -> int:
     """Power-of-two bucket of the largest operand's element count.
 
-    Mirrors the bucketing the tuning tables key on, so events can be joined
-    against autotune entries.
+    Events of one op that differ only within a power of two share a bucket.
     """
     biggest = 0
     for shp in shapes:

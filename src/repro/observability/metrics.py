@@ -9,7 +9,7 @@ latency percentiles, without inventing ad-hoc dicts in every module.
 * :class:`Gauge` — last-write-wins (achieved GB/s, frac-of-bound);
 * :class:`Histogram` — count/sum/min/max + power-of-two bucket counts
   (wall-time distributions; pow2 buckets match the shape buckets used by
-  dispatch events and tuning tables).
+  dispatch events).
 
 Metrics are named and labelled (``gauge("spmv_gbs", op="spmv_csr",
 executor="xla")``); a ``(name, labels)`` pair identifies one time series.

@@ -12,7 +12,7 @@ cost and repeat-values traffic pays neither.
 
 :mod:`repro.serve.service` wraps the engine in a background thread behind an
 async request queue; :mod:`repro.serve.traffic` generates synthetic Poisson
-traffic over a pattern gallery for benchmarks and the CI smoke gate.
+traffic over a pattern gallery for the CI smoke gate.
 """
 
 from repro.serve.cache import (

@@ -1,4 +1,4 @@
-"""Launch-configuration subsystem: tables, VMEM budget, alignment, autotune."""
+"""Launch-configuration subsystem: tables, VMEM budget, alignment."""
 
 import dataclasses
 
@@ -104,54 +104,31 @@ def test_table_override_wins_over_seed():
         tuning._TABLE.pop(("nn_rmsnorm", target), None)
 
 
-def test_autotune_cache_roundtrip(tmp_path):
-    shapes = {"rows": 1000, "d": 333, "itemsize": 4}
+def test_stale_table_entry_missing_params_is_ignored():
+    """An override that lacks the current spec's params falls back to the
+    seed instead of crashing the kernel call."""
+    shapes = {"S": 128, "Skv": 128, "D": 64, "itemsize": 4}
+    seeded = tuning.resolve("nn_attention", shapes, hw_params.TPU_V5E)
     try:
-        tuning.record_autotuned("nn_rmsnorm", "tpu_v5e", shapes, {"block_rows": 64})
-        # same bucket (pow2-rounded sizes) hits the cache
-        cfg = tuning.resolve(
-            "nn_rmsnorm", {"rows": 1024, "d": 512, "itemsize": 4},
-            hw_params.TPU_V5E,
-        )
-        assert cfg["block_rows"] == 64
-        assert cfg.source == "autotuned"
-        # a different bucket falls back to the table
-        other = tuning.resolve(
-            "nn_rmsnorm", {"rows": 64, "d": 64, "itemsize": 4}, hw_params.TPU_V5E
-        )
-        assert other.source == "table"
-        # persistence roundtrip
-        path = tmp_path / "tpu_v5e.json"
-        n = tuning.save_table(str(path), target="tpu_v5e")
-        assert n == 1
-        tuning.clear_autotune_cache()
-        assert tuning.load_table(str(path)) == 1
-        again = tuning.resolve(
-            "nn_rmsnorm", {"rows": 1024, "d": 512, "itemsize": 4},
-            hw_params.TPU_V5E,
-        )
-        assert again.source == "autotuned" and again["block_rows"] == 64
+        tuning.set_table_entry("nn_attention", "tpu_v5e", {"block_q": 64})
+        cfg = tuning.resolve("nn_attention", shapes, hw_params.TPU_V5E)
+        assert cfg.source == seeded.source
+        assert cfg.block == seeded.block
     finally:
-        tuning.clear_autotune_cache()
+        tuning._TABLE.pop(("nn_attention", "tpu_v5e"), None)
 
 
-def test_stale_cache_entry_missing_params_is_ignored():
-    """Entries from hand-edited / older-spec tables that lack the current
-    spec's params must fall back to the seed, not crash the kernel call."""
-    try:
-        tuning.record_autotuned(
-            "nn_attention", "tpu_v5e",
-            {"S": 128, "Skv": 128, "D": 64, "itemsize": 4},
-            {"block_q": 64},  # missing block_kv
-        )
-        cfg = tuning.resolve(
-            "nn_attention", {"S": 128, "Skv": 128, "D": 64, "itemsize": 4},
-            hw_params.TPU_V5E,
-        )
-        assert cfg.source.startswith("table")  # fell back to the seed
-        assert set(cfg.block) == {"block_q", "block_kv"}
-    finally:
-        tuning.clear_autotune_cache()
+def test_shrunk_geometry_is_marked():
+    """``source`` is ``"table"`` unless the budget check shrank the geometry."""
+    shapes = OPS_AND_SHAPES["nn_rmsnorm"]
+    big = tuning.resolve("nn_rmsnorm", shapes, hw_params.CPU_INTERPRET)
+    tiny = tuning.resolve(
+        "nn_rmsnorm", shapes,
+        dataclasses.replace(hw_params.CPU_INTERPRET, vmem_limit_bytes=4 * 1024 * 1024),
+    )
+    assert big.source == "table"
+    assert tiny.source == "table+shrunk"
+    assert tiny["block_rows"] < big["block_rows"]
 
 
 def test_executor_launch_config_entry_point():
@@ -172,11 +149,6 @@ def test_unknown_op_raises():
         tuning.resolve("no_such_op", {}, hw_params.CPU_XLA)
 
 
-def test_bucketing_pow2():
-    assert tuning.next_pow2(1) == 1
-    assert tuning.next_pow2(3) == 4
-    assert tuning.next_pow2(1024) == 1024
-    b1 = tuning.bucket_shapes({"S": 1000, "itemsize": 4})
-    b2 = tuning.bucket_shapes({"S": 1024, "itemsize": 4})
-    assert b1 == b2
-    assert tuning.bucket_shapes({"S": 1025, "itemsize": 4}) != b1
+@pytest.mark.parametrize("n, want", [(0, 1), (1, 1), (3, 2), (1024, 1024), (1025, 1024)])
+def test_prev_pow2(n, want):
+    assert tuning.prev_pow2(n) == want

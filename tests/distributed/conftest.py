@@ -68,7 +68,7 @@ def _run_with_devices(body: str, n: int = 8) -> str:
     return out.stdout
 
 
-@pytest.fixture
+@pytest.fixture(scope="session")
 def run_with_devices():
     """Callable fixture: run a script in a subprocess with forced devices."""
     return _run_with_devices

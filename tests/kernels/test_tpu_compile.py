@@ -2,10 +2,11 @@
 
 Nothing runs: each test lowers through the library's normal dispatch (a
 :class:`PallasTpuExecutor`, the registry, the tuning tables) and compiles for
-one chip of a ``v5e:2x2`` topology that is described, not attached — what the
-chip's compiler would refuse (block shapes off the (8, 128) tiling, scalar
-stores to VMEM, more VMEM than the kernel asked for) fails here.  Sizes are
-the 128³ Poisson system the chip smoke test solves: 7 ELL slots per row,
+one chip, or all four, of a ``v5e:2x2`` topology that is described, not
+attached — what the chip's compiler would refuse (block shapes off the
+(8, 128) tiling, scalar stores to VMEM, more VMEM than the kernel asked for)
+fails here.  Sizes are
+the 128³ Poisson system the chip benchmark solves: 7 ELL slots per row,
 8x8 Jacobi blocks.
 
 The topology is described inside a fixture, never at import, so every test
@@ -49,6 +50,19 @@ def one_chip(topo):
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """A 1-D mesh over the four described chips, which the distributed solve
+    takes in place of the host's devices."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield Mesh(np.asarray(topo.devices[:4]), ("data",))
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
@@ -239,3 +253,51 @@ def test_fused_cg_amg_compiles(one_chip, ex):
     # the fine level's band kernels and the transfers' packed kernel
     assert "spmv_dot_ell_band" in text and "spmv_ell_band" in text
     assert text.count("tpu_custom_call") >= 2 * M_.num_levels
+
+
+def test_dist_cg_jacobi_four_slabs_compiles(four_chips, ex, monkeypatch):
+    """CG + scalar Jacobi on ``DistEll`` over four row slabs of 524,288 rows,
+    one per chip: the halo ``all_gather``, the ``psum`` reductions and each
+    slab's Pallas SpMV in one program.  Interior slots hold the full 7-point
+    row, boundary rows 6 local slots and 1 halo slot; a slab's halo is one
+    128 x 128 plane per neighbour."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.distributed import DistEll, Partition
+    from repro.launch import mesh as mesh_lib
+    from repro.solvers import krylov
+    from repro.solvers.common import Stop
+
+    monkeypatch.setattr(
+        mesh_lib, "make_shard_mesh", lambda n, axis="data": four_chips
+    )
+    parts, plane = 4, 128 * 128
+    L = M // parts
+
+    def slabs(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(
+            (parts,) + shape, dtype, sharding=NamedSharding(four_chips, P("data"))
+        )
+
+    A = DistEll(
+        int_col_idx=slabs(L, K, dtype=jnp.int32), int_values=slabs(L, K),
+        bnd_col_idx=slabs(L, K - 1, dtype=jnp.int32), bnd_values=slabs(L, K - 1),
+        halo_col_idx=slabs(L, 1, dtype=jnp.int32), halo_values=slabs(L, 1),
+        halo_map=slabs(2 * plane, dtype=jnp.int32),
+        shape=(M, M), nnz=K * M - 6 * plane,
+        partition=Partition.uniform(M, parts),
+        _halo_counts=(plane, 2 * plane, 2 * plane, plane),
+    )
+    leaves, tree = jax.tree_util.tree_flatten(A)
+
+    def solve(leaves, b):
+        res = krylov.cg(jax.tree_util.tree_unflatten(tree, leaves), b,
+                        M="jacobi", executor=ex,
+                        stop=Stop(max_iters=5000, reduction_factor=1e-6))
+        return res.x, res.iterations
+
+    b = jax.ShapeDtypeStruct((M,), jnp.float32,
+                             sharding=NamedSharding(four_chips, P("data")))
+    text = _compile(solve, leaves, b)
+    assert "while" in text
+    assert "all-gather" in text and "all-reduce" in text

@@ -1,8 +1,7 @@
 """Convergence telemetry: ``history=`` on every solver, plus the acceptance
-pin that a traced CG solve reproduces the PR-6 launch-count structure."""
+pin that a traced CG solve reproduces the pinned launch-count structure."""
 
 import json
-import os
 
 import numpy as np
 import jax
@@ -16,9 +15,9 @@ from repro.solvers import krylov
 from repro.solvers.common import Stop
 from repro.solvers.ir import ir, mixed_precision_ir
 
-BENCH_PR6 = os.path.join(
-    os.path.dirname(__file__), "..", "..", "BENCH_pr6.json"
-)
+#: dispatches in the CG loop body: the fused spmv_dot + axpy_norm, or the
+#: seven unfused spmv / dot / norm / axpy ops
+BODY_LAUNCHES = {True: 2, False: 7}
 
 
 @pytest.fixture(autouse=True)
@@ -210,19 +209,12 @@ def _body_launches(counts, fused):
     )
 
 
-@pytest.mark.skipif(
-    not os.path.exists(BENCH_PR6), reason="BENCH_pr6.json not present"
-)
 @pytest.mark.parametrize("fused", [True, False])
 def test_traced_cg_matches_bench_pins(tmp_path, fused):
     """A traced CG solve must produce a valid Chrome trace whose dispatch
-    span counts reproduce the pinned PR-6 launch structure (2 fused / 7
-    unfused body launches) — the trace is the pins' live counterpart."""
-    with open(BENCH_PR6) as f:
-        pinned = json.load(f)["pinned"]
-    want = pinned[
-        "fused_cg_body_launches" if fused else "unfused_cg_body_launches"
-    ]
+    span counts reproduce the pinned launch structure (2 fused / 7 unfused
+    body launches) — the trace is the pins' live counterpart."""
+    want = BODY_LAUNCHES[fused]
 
     a, b = _system(n=96, seed=3)
     A = sparse.csr_from_dense(a)
