@@ -35,7 +35,11 @@ densely in float64 on the host, or solved by CG; a coarsest level above
 The operand may be CSR or ELL, and the setup reads its host pattern and
 values (:func:`repro.sparse.formats.csr_host_arrays`).  The fine level
 applies an ELL operand as handed and converts a CSR one to ELL once; coarse
-operators and the transfers are ELL too.
+operators and the transfers are ELL too, except below a level whose operator
+is a symmetric band ELL smoothed by weighted Jacobi: that level applies its
+``P``, ``R`` and the next level's Galerkin operator through its own band
+operator and its aggregate map (:class:`FactoredTransfer`), and the setup
+keeps their host products only to aggregate the next level.
 
 The cycle (V or W) runs weighted-Jacobi or block-Jacobi smoothers per level
 and the coarse solve; the recursion is unrolled at trace time, so
@@ -48,11 +52,13 @@ device trace: ``Multigrid.level<k>`` (level k's smoothing and residual),
 ``Multigrid.restrict<k>`` / ``Multigrid.prolong<k>`` (the transfers between
 level k and k+1) and ``Multigrid.coarse``.
 
-Setup emits ``amg.setup`` / ``amg.level`` / ``amg.coarse_solver`` spans and
+Setup emits ``amg.setup`` / ``amg.level`` (arg ``layout``) /
+``amg.coarse_solver`` spans, the counter
+``amg.transfer_layout{level, layout=factored|stored}`` (one per level) and
 the gauges ``amg_level_rows{level}``, ``amg_level_nnz{level}``,
-``amg_transfer_nnz{level}`` (stored nonzeros of ``P_k`` and ``R_k``) and
-``amg_operator_complexity`` (Σ level nnz / fine nnz) — the standard AMG
-cost metric.
+``amg_transfer_nnz{level}`` (nonzeros of ``P_k`` and ``R_k``, whether stored
+or factored) and ``amg_operator_complexity`` (Σ level nnz / fine nnz) — the
+standard AMG cost metric.
 
 The serve layer uses the pattern-only subset at the bottom of this module:
 aggregation from the sparsity pattern alone plus an additive two-level
@@ -75,6 +81,7 @@ from repro.observability import metrics, trace
 from repro.sparse.formats import (
     Csr,
     Ell,
+    _transpose_host,
     csr_from_arrays,
     csr_host_arrays,
     ell_from_csr_host,
@@ -85,6 +92,7 @@ __all__ = [
     "DENSE_COARSE_MAX_ROWS",
     "AmgLevel",
     "AmgServePattern",
+    "FactoredTransfer",
     "Multigrid",
     "aggregate",
     "amg_preconditioner",
@@ -208,23 +216,99 @@ def _csr_sub_scaled(Tm: Csr, S: Csr, row_scale: np.ndarray) -> Csr:
     return csr_from_arrays(indptr, out_c, out_v, (m, n))
 
 
+def _is_symmetric(indptr, indices, values, n) -> bool:
+    """Whether the CSR triplet (each row's columns ascending) equals its
+    transpose, values bit for bit."""
+    return all(np.array_equal(a, b) for a, b in zip(
+        (indptr, indices, values), _transpose_host(indptr, indices, values, n, n)))
+
+
+def _members_ell(agg: np.ndarray, n_agg: int, dtype) -> Ell:
+    """``Tᵀ`` as ELL: row c lists the fine rows of aggregate c, value 1."""
+    n = agg.size
+    t = _transpose_host(np.arange(n + 1), agg, np.ones(n, dtype), n, n_agg)
+    return ell_from_csr_host(*t, (n_agg, n))
+
+
+@dataclasses.dataclass(frozen=True)
+class FactoredTransfer(LinOp):
+    """One of ``P``, ``R`` and the Galerkin ``A_c`` of a level, applied
+    through the level's band operator and its aggregate map instead of as
+    stored ELL (``part`` is ``"P"``, ``"R"`` or ``"A"``):
+
+    - ``P x_c = T x_c − ω D⁻¹ A (T x_c)`` with ``T x_c = x_c[agg]``;
+    - ``R y = Tᵀ (y − ω A (D⁻¹ y))``, which is ``Pᵀ y`` because ``A = Aᵀ``;
+    - ``A_c x_c = R (A (P x_c))``.
+
+    ``Tᵀ`` sums each aggregate's members (``members``, an ELL of unit
+    values).  ``omega`` is 0 for an unsmoothed prolongator, ``P = T``.  The
+    three parts of one level share the same arrays.
+    """
+
+    A: Ell
+    inv_diag: jax.Array
+    agg: jax.Array
+    members: Ell
+    omega: float
+    part: str
+
+    @property
+    def shape(self):
+        n, n_agg = self.A.shape[0], self.members.shape[0]
+        return {"P": (n, n_agg), "R": (n_agg, n), "A": (n_agg, n_agg)}[self.part]
+
+    @property
+    def dtype(self):
+        return self.A.dtype
+
+    def _prolong(self, xc, ex):
+        x = xc[self.agg]
+        if self.omega:
+            x = x - self.omega * self.inv_diag * sp_apply(self.A, x, executor=ex)
+        return x
+
+    def _restrict(self, y, ex):
+        if self.omega:
+            y = y - self.omega * sp_apply(self.A, self.inv_diag * y, executor=ex)
+        return sp_apply(self.members, y, executor=ex)
+
+    def _apply(self, x, ex):
+        if self.part == "P":
+            return self._prolong(x, ex)
+        if self.part == "R":
+            return self._restrict(x, ex)
+        return self._restrict(sp_apply(self.A, self._prolong(x, ex), executor=ex), ex)
+
+
+jax.tree_util.register_dataclass(
+    FactoredTransfer,
+    data_fields=["A", "inv_diag", "agg", "members"],
+    meta_fields=["omega", "part"],
+)
+
+
 @dataclasses.dataclass
 class AmgLevel:
     """One level of the hierarchy: its operator, grid-transfer pair, and the
     smoother data (inverse diagonal for weighted Jacobi, or a block-Jacobi
     LinOp when the hierarchy was built with ``smoother="block_jacobi"``).
 
-    ``A`` is what the cycle applies, always ELL: on level 0 the operand
-    :class:`Multigrid` was handed (converted once if it came as CSR), below
-    it the Galerkin product.  The
-    transfers ``P`` (coarse → fine) and ``R = Pᵀ`` (fine → coarse) are ELL
-    too — PDE hierarchies have near-uniform row counts, and the ELL SpMV
-    needs no per-apply row-id reconstruction.
+    ``A`` is what the cycle applies: on level 0 the operand
+    :class:`Multigrid` was handed (converted once to ELL if it came as CSR),
+    below it the Galerkin product.  The transfers ``P`` (coarse → fine) and
+    ``R = Pᵀ`` (fine → coarse) and the next level's ``A`` are stored as ELL
+    — PDE hierarchies have near-uniform row counts, and the ELL SpMV needs
+    no per-apply row-id reconstruction — unless the level's operator is a
+    symmetric band ELL smoothed by weighted Jacobi: then all three are
+    :class:`FactoredTransfer` views (layout ``factored``), whose applies
+    gather about one index per fine row where the stored ones gather one
+    per stored entry.  The coarsest operator, which the coarse solve reads,
+    is always stored.
     """
 
-    A: Ell
-    P: Ell
-    R: Ell
+    A: LinOp
+    P: LinOp
+    R: LinOp
     inv_diag: jax.Array
     smoother: Optional[LinOp] = None
 
@@ -304,13 +388,23 @@ class Multigrid(LinOp):
                 if 2 * n_agg > n:
                     break  # coarsening stalls: this level is the coarsest
                 with trace.span("amg.level", cat="amg", level=level,
-                                rows=n, nnz=indices.size, coarse_rows=n_agg):
+                                rows=n, nnz=indices.size,
+                                coarse_rows=n_agg) as span:
+                    # the factored transfers' R = Tᵀ(I − ω A D⁻¹) is Pᵀ only
+                    # for a symmetric A, and their applies gain only where A's
+                    # own SpMV gathers nothing (the band layout)
+                    factored = (smoother == "jacobi"
+                                and isinstance(op, Ell) and op.offsets is not None
+                                and _is_symmetric(indptr, indices, values, n))
+                    layout = "factored" if factored else "stored"
+                    span.annotate(layout=layout)
                     A_csr = csr_from_arrays(indptr, indices, values, (n, n))
                     T = tentative_prolongator(agg, n_agg)
                     diag = _csr_diag(indptr, indices, values, n)
                     inv_d = np.where(diag != 0, 1.0 / diag, 0.0).astype(
                         values.dtype
                     )
+                    inv_diag = jnp.asarray(inv_d)
                     if smooth_prolongator:
                         AT = spgemm(A_csr, T, executor=executor)
                         P = _csr_sub_scaled(T, AT, self.omega * inv_d)
@@ -326,20 +420,38 @@ class Multigrid(LinOp):
                         sm = block_jacobi(
                             op, executor=executor, **(smoother_opts or {})
                         )
+                    if factored:
+                        parts = dict(
+                            A=op, inv_diag=inv_diag,
+                            agg=jnp.asarray(agg, jnp.int32),
+                            members=_members_ell(agg, n_agg, values.dtype),
+                            omega=self.omega if smooth_prolongator else 0.0,
+                        )
+                        P_op, R_op, coarse_op = (
+                            FactoredTransfer(**parts, part=p) for p in "PRA"
+                        )
+                    else:
+                        P_op, R_op = _ell_of(P), _ell_of(R)
                     self.levels.append(
-                        AmgLevel(A=op, P=_ell_of(P), R=_ell_of(R),
-                                 inv_diag=jnp.asarray(inv_d), smoother=sm)
+                        AmgLevel(A=op, P=P_op, R=R_op, inv_diag=inv_diag,
+                                 smoother=sm)
                     )
+                metrics.counter("amg.transfer_layout", level=level,
+                                layout=layout).inc()
                 metrics.gauge("amg_level_rows", level=level).set(n)
                 metrics.gauge("amg_level_nnz", level=level).set(indices.size)
                 metrics.gauge("amg_transfer_nnz", level=level).set(P.nnz + R.nnz)
                 total_nnz += indices.size
                 indptr, indices, values = csr_host_arrays(A_c)
-                op = ell_from_csr_host(indptr, indices, values, A_c.shape)
+                op = coarse_op if factored else ell_from_csr_host(
+                    indptr, indices, values, A_c.shape)
                 n = n_agg
                 level += 1
 
-            self.coarse_A = op
+            # the coarse solve reads the coarsest operator stored, even below
+            # a factored level
+            self.coarse_A = op if isinstance(op, Ell) else ell_from_csr_host(
+                indptr, indices, values, (n, n))
             metrics.gauge("amg_level_rows", level=level).set(n)
             metrics.gauge("amg_level_nnz", level=level).set(indices.size)
             self.operator_complexity = (total_nnz + indices.size) / fine_nnz

@@ -86,12 +86,12 @@ def _ell(one_chip, offsets=None):
     )
 
 
-def _row_gathers(text):
-    """The compiled program's gathers whose result has ``M`` elements."""
+def _row_gathers(text, elements=M):
+    """The compiled program's gathers whose result has ``elements`` elements."""
     found = []
     for line in text.splitlines():
         m = re.search(r"= [a-z0-9]+\[([0-9,]*)\]\S* gather\(", line)
-        if m and math.prod(int(d) for d in m.group(1).split(",") if d) == M:
+        if m and math.prod(int(d) for d in m.group(1).split(",") if d) == elements:
             found.append(line.strip())
     return found
 
@@ -222,13 +222,15 @@ def test_fused_cg_block_jacobi_row_compiles(one_chip, ex):
 
 def test_fused_cg_amg_compiles(one_chip, ex):
     """CG with one smoothed-aggregation V-cycle as ``M``, the hierarchy a
-    jit argument: the band SpMV on the fine level, the rectangular packed
-    ``spmv_ell`` of every transfer and the packed coarse operators inside the
-    loop.  The hierarchy is built here on the CPU at 32³ and handed over as
-    shapes."""
+    jit argument: the band SpMV on the fine level and inside level 0's
+    factored transfers and level 1's factored operator, the rectangular
+    packed ``spmv_ell`` of the stored transfers and the packed coarse
+    operators inside the loop.  The hierarchy is built here on the CPU at 32³
+    and handed over as shapes."""
     import numpy as np
 
     from repro import sparse
+    from repro.kernels import lanes
     from repro.precond import make_preconditioner
     from repro.solvers import krylov
     from repro.solvers.common import Stop
@@ -238,7 +240,14 @@ def test_fused_cg_amg_compiles(one_chip, ex):
     A = sparse.ell_from_csr_host(indptr, indices, values.astype(np.float32), shape)
     M_ = make_preconditioner(A, "amg", executor=ex, theta=0.0)
     assert M_.num_levels >= 3
-    assert any(L.R.offsets is None and L.R.shape[0] < L.R.shape[1] for L in M_.levels)
+    stored = [L.R for L in M_.levels if isinstance(L.R, sparse.Ell)]
+    assert any(R.offsets is None and R.shape[0] < R.shape[1] for R in stored)
+    # the stored A_1 would gather its 34 slots, padded to 64 by block_k 32,
+    # over its row blocks: (64, rows, 128)
+    n_agg = M_.levels[1].A.shape[0]
+    block_m = ex.launch_config("spmv_ell", {"m": n_agg, "k": 34, "n": n_agg,
+                                            "itemsize": 4})["block_m"]
+    rows, _ = lanes.row_tiling(n_agg, block_m, jnp.float32, jnp.float32)
 
     def shapes(tree):
         return jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype), tree)
@@ -253,6 +262,7 @@ def test_fused_cg_amg_compiles(one_chip, ex):
     # the fine level's band kernels and the transfers' packed kernel
     assert "spmv_dot_ell_band" in text and "spmv_ell_band" in text
     assert text.count("tpu_custom_call") >= 2 * M_.num_levels
+    assert _row_gathers(text, 64 * rows * lanes.LANES) == []
 
 
 def test_dist_cg_jacobi_four_slabs_compiles(four_chips, ex, monkeypatch):

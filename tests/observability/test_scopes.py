@@ -48,6 +48,13 @@ def _cg_hlo(A, M, ex) -> str:
     return fn.lower(A, M, b).compile().as_text()
 
 
+def _applied(op, ex) -> np.ndarray:
+    """``op`` as a dense matrix: its apply on each unit column."""
+    eye = jnp.eye(op.shape[1], dtype=jnp.float32)
+    cols = jax.jit(jax.vmap(lambda e: sparse.apply(op, e, executor=ex)))(eye)
+    return np.asarray(cols).T
+
+
 def _instructions(hlo: str) -> list:
     """``[(name, opcode, op_name)]`` of every instruction of an HLO text."""
     out = []
@@ -191,7 +198,8 @@ def test_the_multigrid_cycle_names_its_levels_transfers_and_coarse_solve():
     assert not any({"Multigrid.level0", "Multigrid.level1"} <= p for p in parts)
     gauges = {(s["name"], s["labels"].get("level")): s["value"] for s in metrics.samples()}
     for k, L in enumerate(M.levels):
-        stored = int(np.count_nonzero(np.asarray(L.P.values))
-                     + np.count_nonzero(np.asarray(L.R.values)))
-        assert gauges["amg_transfer_nnz", str(k)] == stored
+        # the entries of P_k and R_k as the cycle applies them, stored or
+        # factored: each operator on every unit column
+        applied = sum(int(np.count_nonzero(_applied(op, ex))) for op in (L.P, L.R))
+        assert gauges["amg_transfer_nnz", str(k)] == applied
     assert ("amg_transfer_nnz", str(M.num_levels - 1)) not in gauges

@@ -45,6 +45,13 @@ def _dense(C):
     return np.asarray(sparse.to_dense(C, executor=make_executor("reference")))
 
 
+def _applied(op):
+    """``op`` as the cycle applies it, stored or factored: its apply on each
+    unit column, as a dense matrix."""
+    eye = jnp.eye(op.shape[1], dtype=jnp.float32)
+    return np.asarray(jax.jit(jax.vmap(lambda e: sparse.apply(op, e)))(eye)).T
+
+
 # =============================================================================
 # setup pipeline
 # =============================================================================
@@ -87,10 +94,10 @@ def test_galerkin_matches_dense_triple_product():
     A = _poisson(10)
     M = Multigrid(A, max_levels=1, coarse_size=8)
     L = M.levels[0]
-    a, p, r = _dense(L.A), _dense(L.P), _dense(L.R)
+    a, p, r = _applied(L.A), _applied(L.P), _applied(L.R)
     np.testing.assert_allclose(r, p.T, atol=1e-6)
     np.testing.assert_allclose(
-        _dense(M.coarse_A), r @ a @ p, atol=1e-3, rtol=1e-3
+        _applied(M.coarse_A), r @ a @ p, atol=1e-3, rtol=1e-3
     )
 
 
@@ -332,10 +339,9 @@ def test_an_ell_operand_gives_the_levels_and_cycle_of_the_equal_csr():
     assert M_ell.num_levels == M_csr.num_levels
     for a, b in zip(M_ell.levels, M_csr.levels):
         for name in ("P", "R"):
-            np.testing.assert_array_equal(np.asarray(getattr(a, name).col_idx),
-                                          np.asarray(getattr(b, name).col_idx))
-            np.testing.assert_allclose(np.asarray(getattr(a, name).values),
-                                       np.asarray(getattr(b, name).values),
+            assert type(getattr(a, name)) is type(getattr(b, name))
+            np.testing.assert_allclose(_applied(getattr(a, name)),
+                                       _applied(getattr(b, name)),
                                        rtol=1e-6, atol=1e-7)
     r = jnp.asarray(np.random.default_rng(7).normal(size=shape[0]), jnp.float32)
     np.testing.assert_allclose(np.asarray(M_ell.apply(r)), np.asarray(M_csr.apply(r)),
@@ -360,3 +366,116 @@ def test_multigrid_is_a_pytree_that_a_jitted_solve_takes():
     assert solve._cache_size() == 1  # one compile serves every right-hand side
     r = b - sparse.apply(A, res.x)
     assert float(jnp.linalg.norm(r)) <= 2e-6 * float(jnp.linalg.norm(b))
+
+
+# =============================================================================
+# the factored band level: P, R and the next level's A through A and agg
+# =============================================================================
+
+
+def _band_and_packed(n_side):
+    """The 3-D Poisson operator plus a diagonal that varies by row (so that
+    ``D⁻¹`` is no multiple of the identity), as band and as packed ELL."""
+    from repro.sparse.formats import ell_packed
+
+    indptr, indices, values, shape = _poisson3d(n_side)
+    rows = np.repeat(np.arange(shape[0]), np.diff(indptr))
+    values = values.copy()
+    values[indices == rows] += 0.5 * (rows[indices == rows] % 7)
+    band = sparse.ell_from_csr_host(indptr, indices, values, shape)
+    assert band.offsets is not None
+    return band, ell_packed(band)
+
+
+def _layouts():
+    from repro.observability import metrics
+
+    return {int(s["labels"]["level"]): s["labels"]["layout"]
+            for s in metrics.samples() if s["name"] == "amg.transfer_layout"}
+
+
+@pytest.mark.parametrize("cycle, smooth", [("v", True), ("w", True), ("v", False)])
+def test_the_factored_cycle_matches_the_stored_cycle(cycle, smooth):
+    """The band operand factors level 0's transfers and level 1's operator;
+    the same operator handed as packed ELL stores them: one cycle of each
+    agrees to float32 rounding, with the prolongator smoothed or not."""
+    from repro.precond.amg import FactoredTransfer
+
+    band, packed = _band_and_packed(16)
+    opts = dict(theta=0.0, cycle=cycle, smooth_prolongator=smooth)
+    M_f = make_preconditioner(band, "amg", **opts)
+    M_s = make_preconditioner(packed, "amg", **opts)
+    assert M_f.num_levels == M_s.num_levels >= 3
+    L0 = M_f.levels[0]
+    assert all(isinstance(op, FactoredTransfer) for op in (L0.P, L0.R, M_f.levels[1].A))
+    assert all(isinstance(op, sparse.Ell) for op in (M_s.levels[0].P, M_s.levels[1].A))
+    r = jnp.asarray(np.random.default_rng(9).normal(size=band.shape[0]), jnp.float32)
+    cyc = jax.jit(lambda M, r: M.apply(r))
+    got, want = np.asarray(cyc(M_f, r)), np.asarray(cyc(M_s, r))
+    assert np.linalg.norm(got - want) <= 1e-5 * np.linalg.norm(want)
+
+
+@pytest.mark.parametrize("part", ["P", "R", "A"])
+def test_the_factored_operators_match_the_host_galerkin_product(part):
+    """On unit vectors the factored ``P_0``, ``R_0`` and ``A_1`` give the
+    host's ``P = (I − ω D⁻¹ A) T``, its transpose and the host Galerkin
+    ``R A P`` (stored by the packed operand's hierarchy)."""
+    band, packed = _band_and_packed(8)
+    M_f = make_preconditioner(band, "amg", theta=0.0)
+    M_s = make_preconditioner(packed, "amg", theta=0.0)
+    L = M_f.levels[0]
+    a = _dense(band).astype(np.float64)
+    agg = np.asarray(L.P.agg)
+    t = np.zeros((a.shape[0], int(agg.max()) + 1))
+    t[np.arange(a.shape[0]), agg] = 1.0
+    p = t - M_f.omega * (a / np.diag(a)[:, None]) @ t
+    host = {"P": p, "R": p.T, "A": _dense(M_s.levels[1].A)}[part]
+    got = _applied(L.P if part == "P" else L.R if part == "R" else M_f.levels[1].A)
+    assert got.shape == host.shape
+    np.testing.assert_allclose(got, host, rtol=1e-5, atol=1e-5 * np.abs(host).max())
+
+
+def _nonsymmetric_band(n_side):
+    indptr, indices, values, shape = _poisson3d(n_side)
+    rows = np.repeat(np.arange(shape[0]), np.diff(indptr))
+    values = values.copy()
+    values[indices == rows + 1] *= 1.25  # upwinded convection along x
+    return sparse.ell_from_csr_host(indptr, indices, values, shape)
+
+
+@pytest.mark.parametrize("case, layout0", [
+    ("band", "factored"),
+    ("packed", "stored"),
+    ("nonsymmetric band", "stored"),
+    ("block_jacobi smoother", "stored"),
+])
+def test_the_transfer_layout_is_observed_on_the_operand(case, layout0):
+    """Only a symmetric band operand smoothed by weighted Jacobi factors its
+    level; every level below a packed operator stores its transfers, and
+    ``amg.transfer_layout`` counts one layout per level."""
+    from repro.observability import metrics
+    from repro.precond.amg import FactoredTransfer
+
+    band, packed = _band_and_packed(12)
+    A = {"packed": packed, "nonsymmetric band": _nonsymmetric_band(12)}.get(case, band)
+    opts = {"smoother": "block_jacobi"} if case == "block_jacobi smoother" else {}
+    metrics.reset()
+    M = make_preconditioner(A, "amg", theta=0.0, **opts)
+    layouts = _layouts()
+    assert sorted(layouts) == list(range(len(M.levels)))
+    assert layouts[0] == layout0
+    assert all(layouts[k] == "stored" for k in range(1, len(M.levels)))
+    for k, L in enumerate(M.levels):
+        kind = FactoredTransfer if layouts[k] == "factored" else sparse.Ell
+        assert isinstance(L.P, kind) and isinstance(L.R, kind)
+
+
+def test_factored_and_stored_cg_take_the_same_iterations():
+    """CG + AMG on the band operand (factored level 0) and on the same
+    operator as packed ELL (stored) converge in as many iterations."""
+    band, packed = _band_and_packed(16)
+    b = jnp.asarray(np.random.default_rng(10).normal(size=band.shape[0]), jnp.float32)
+    stop = Stop(max_iters=100, reduction_factor=1e-6)
+    its = [int(cg(A, b, stop=stop, M="amg", precond_opts={"theta": 0.0}).iterations)
+           for A in (band, packed)]
+    assert its[0] == its[1] <= 15
